@@ -1,6 +1,5 @@
 """repro: production-grade JAX reproduction of Rubik (hierarchical GCN
 learning: LSH graph reordering + computation reuse + hierarchical mapping),
 scaled to multi-pod TPU meshes."""
-from .dist import compat as _compat  # noqa: F401  (jax API shims; cheap)
 
 __version__ = "1.0.0"

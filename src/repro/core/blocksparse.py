@@ -267,20 +267,26 @@ def transpose_graph(g: Graph) -> Graph:
     return dataclasses.replace(g, src=g.dst, dst=g.src)
 
 
-def traffic_model(ell: BlockEll, d: int, bytes_per_el: int = 4
-                  ) -> dict:
+def traffic_model(ell: BlockEll, d: int, bytes_per_el: int = 4,
+                  adj_itemsize: Optional[int] = None) -> dict:
     """HBM traffic of one block-ELL SpMM vs a pure edge-gather baseline.
 
     gather baseline: every edge loads a d-vector (no reuse) = nnz * d * B.
     block-ELL:       one (bk, d) tile per active block + output writes +
-                     the adjacency tiles themselves (at their storage width:
-                     the implicit bitmask streams 32x fewer adjacency bytes).
+                     the adjacency tiles themselves, at ``adj_itemsize``
+                     bytes per entry when the kernel is fed unpacked tiles,
+                     else at their storage width (the implicit bitmask
+                     streams 32x fewer adjacency bytes than float32).
     The ratio is the TPU analogue of the paper's off-chip traffic reduction.
     """
     stats = ell.density_stats()
     gather = stats["nnz"] * d * bytes_per_el
-    adj_bytes = (ell.n_active * ell.bm * ((ell.bk + 7) // 8) if ell.implicit
-                 else ell.n_active * ell.bm * ell.bk * ell.dtype.itemsize)
+    if adj_itemsize is not None:
+        adj_bytes = ell.n_active * ell.bm * ell.bk * adj_itemsize
+    elif ell.implicit:
+        adj_bytes = ell.n_active * ell.bm * ((ell.bk + 7) // 8)
+    else:
+        adj_bytes = ell.n_active * ell.bm * ell.bk * ell.dtype.itemsize
     blocked = (stats["active_blocks"] * ell.bk * d * bytes_per_el
                + ell.n_row_blocks * ell.bm * d * bytes_per_el
                + adj_bytes)

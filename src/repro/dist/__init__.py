@@ -6,12 +6,10 @@ reduction across devices with collective volume proportional to what the
 computation actually needs — cut-edge rows, LSE partials, compressed grads —
 instead of full-table all-gathers.
 
-Submodules load lazily (PEP 562): ``repro/__init__`` imports this package on
-every ``import repro`` to install the jax compat shims, and eager submodule
-imports here would both slow that down and cycle through repro.nn/models
-(whose modules import ``repro.dist.sharding`` themselves).
+Submodules load lazily (PEP 562): eager submodule imports here would cycle
+through repro.nn/models (whose modules import ``repro.dist.sharding``
+themselves).
 """
-from . import compat  # noqa: F401  (installs jax API shims as a side effect)
 
 _EXPORTS = {
     "ambient_mesh": "sharding", "batch_axes": "sharding",
@@ -34,7 +32,7 @@ _EXPORTS = {
     "make_dist_train_step": "gnn", "train_distributed": "gnn",
 }
 
-__all__ = ["compat", *sorted(_EXPORTS)]
+__all__ = sorted(_EXPORTS)
 
 
 def __getattr__(name):

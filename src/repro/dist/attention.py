@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import compat  # noqa: F401
-
 
 def distributed_decode_attention(mesh: Mesh, q: jax.Array, k: jax.Array,
                                  v: jax.Array, cache_lens: jax.Array,

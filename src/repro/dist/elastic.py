@@ -50,7 +50,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import compat  # noqa: F401
 from .. import obs
 from ..chaos import inject as chaos
 from ..graph.partition import HaloPlan, Partition, build_halo_plan

@@ -21,8 +21,8 @@ from typing import Dict, List, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compat  # noqa: F401
 from .. import obs
 from ..graph.partition import HaloPlan, build_halo_plan
 from ..graph.structure import Graph
@@ -178,12 +178,16 @@ def train_distributed(arch: str = "gcn-cora", steps: int = 20,
 
     n_classes = int(g.labels.max()) + 1
     deg = g.in_degrees().astype(np.float32)
-    batch = {"x": jnp.asarray(g.node_feat),
-             "labels": jnp.asarray(g.labels.astype(np.int32)),
-             "train_mask": jnp.asarray(g.train_mask),
-             "deg": jnp.asarray(deg)}
-    params = dist_gnn_init(jax.random.PRNGKey(0),
-                           [g.node_feat.shape[1], hidden, n_classes])
+    # node-axis arrays go straight from the host to their shards (never
+    # whole onto one device); the small parameters are replicated
+    batch = jax.device_put({"x": g.node_feat,
+                            "labels": g.labels.astype(np.int32),
+                            "train_mask": g.train_mask, "deg": deg},
+                           NamedSharding(mesh, P("data")))
+    params = jax.device_put(
+        dist_gnn_init(jax.random.PRNGKey(0),
+                      [g.node_feat.shape[1], hidden, n_classes]),
+        NamedSharding(mesh, P()))
     opt = adam(lr)
     opt_state = opt.init(params)
     obs.gauge("dist.parts").set(parts)
@@ -205,4 +209,5 @@ def train_distributed(arch: str = "gcn-cora", steps: int = 20,
         obs.counter("dist.steps").inc(steps)
     log(f"dist[{arch}]: {steps} steps, loss {losses[0]:.4f} -> "
         f"{losses[-1]:.4f}")
-    return {"losses": losses, "collective_estimate": est, "params": params}
+    return {"losses": losses, "collective_estimate": est, "params": params,
+            "batch": batch}

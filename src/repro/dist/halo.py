@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import compat  # noqa: F401
 from ..graph.partition import HaloPlan, uniform_local_n
 from .plan import SendPlan
 

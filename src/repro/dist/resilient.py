@@ -32,7 +32,6 @@ from typing import Optional
 
 import jax
 
-from . import compat  # noqa: F401
 from .. import obs
 from ..chaos import inject as chaos
 from .elastic import FAULT_KINDS, ModeledClock, RetryPolicy

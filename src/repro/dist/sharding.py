@@ -20,8 +20,6 @@ from typing import Any, Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat  # noqa: F401  (installs jax API shims)
-
 
 # ------------------------------------------------------------ ambient mesh
 def ambient_mesh() -> Optional[Mesh]:

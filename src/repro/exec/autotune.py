@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs
-from ..core.blocksparse import traffic_model
 from ..graph.structure import Graph
 from .bucketing import (bucket_candidates, bucket_layer_candidates,
                         make_layer_cand, split_graph_cand, split_layer_cand)
@@ -367,11 +366,23 @@ def _modeled_traffic(plan: GraphExecutionPlan, d: int) -> dict:
     if not obs.enabled() or getattr(plan, "_ell", None) is None:
         return {}
     try:
-        t = traffic_model(plan._ell, d)
+        t = plan.traffic(d)
         return {"modeled_gather_bytes": int(t["gather_bytes"]),
                 "modeled_blockell_bytes": int(t["blockell_bytes"])}
     except Exception:
         return {}
+
+
+def _report_failure(sp, backend: str, cand: Sequence,
+                    err: BaseException) -> None:
+    """A candidate that fails to build, compile or run loses the race, but
+    never silently: the failure is counted per backend (ungated, so it
+    shows even with telemetry off), put on the trial span, and printed
+    with the exception text."""
+    obs.counter("exec.autotune.failed", gated=False, backend=backend).inc()
+    sp.set(failed=True, error=type(err).__name__)
+    print(f"autotune: candidate {tuple(cand)} failed: "
+          f"{type(err).__name__}: {err}")
 
 
 def _time_fwd_bwd(plan: GraphExecutionPlan, x: jax.Array,
@@ -451,8 +462,8 @@ def autotune(g: Graph, d: int, mode: str = "gcn", *,
                 plan = build_plan(g, mode, bm=bm, bk=bm, backend=backend,
                                   compact=compact, buckets=bsig)
                 us = _time_fwd_bwd(plan, x, iters=iters)
-            except Exception:  # a candidate failing to build/run just loses
-                sp.set(failed=True)
+            except Exception as err:   # the candidate loses, loudly
+                _report_failure(sp, backend, cand, err)
                 continue
             sp.set(us=us, **_modeled_traffic(plan, d))
         obs.counter("exec.autotune.trials").inc()
@@ -535,6 +546,7 @@ class LayerAutotuneRecord:
     table: Tuple[Tuple, ...]       # bucketed rows carry their sig before us
     from_cache: bool
     buckets: str = ""              # winner's bucket signature ("" = single)
+    failed: Tuple[Tuple, ...] = ()   # candidates that raised when raced
 
     @property
     def order_agrees_with_model(self) -> bool:
@@ -608,7 +620,8 @@ def autotune_layer(g: Graph, d_in: int, d_out: int, mode: str = "gcn", *,
                 compact=bool(e["compact"]), us=float(e["us"]),
                 model_order=str(e.get("model_order", model_order)),
                 table=tuple(tuple(r) for r in e.get("table", ())),
-                from_cache=True, buckets=str(e.get("buckets", "")))
+                from_cache=True, buckets=str(e.get("buckets", "")),
+                failed=tuple(tuple(c) for c in e.get("failed", ())))
         except (KeyError, TypeError, ValueError, AttributeError):
             obs.counter("exec.autotune.cache", result="corrupt").inc()
         else:
@@ -631,6 +644,7 @@ def autotune_layer(g: Graph, d_in: int, d_out: int, mode: str = "gcn", *,
     race = _prune_candidates(cands, model_costs,
                              prune_alpha if prune else None, cache_dir)
     table: List[Tuple] = []
+    failed: List[Tuple] = []
     best = None
     for cand in race:
         order, fuse, backend, bm, compact, bsig = split_layer_cand(cand)
@@ -649,8 +663,9 @@ def autotune_layer(g: Graph, d_in: int, d_out: int, mode: str = "gcn", *,
                                       order=order, fuse=fuse,
                                       gplan=gplans[gkey])
                 us = _time_layer_fwd_bwd(lp, x, w, b, relu, iters=iters)
-            except Exception:  # a candidate failing to build/run just loses
-                sp.set(failed=True)
+            except Exception as err:   # the candidate loses, loudly
+                _report_failure(sp, backend, cand, err)
+                failed.append(tuple(cand))
                 continue
             sp.set(us=us, **_modeled_traffic(gplans[gkey], d_out))
         obs.counter("exec.autotune.trials").inc()
@@ -680,8 +695,8 @@ def autotune_layer(g: Graph, d_in: int, d_out: int, mode: str = "gcn", *,
                                "compact": compact, "buckets": bsig,
                                "us": us,
                                "model_order": model_order, "table": table,
-                               "n": n_nodes, "e": n_edges, "d_in": d_in,
-                               "d_out": d_out, "mode": mode,
+                               "failed": failed, "n": n_nodes, "e": n_edges,
+                               "d_in": d_in, "d_out": d_out, "mode": mode,
                                "device_sig": device_sig(platform)})
     except OSError:
         pass                  # read-only FS: tuning still works, just uncached
@@ -689,7 +704,7 @@ def autotune_layer(g: Graph, d_in: int, d_out: int, mode: str = "gcn", *,
                                backend=backend, bm=bm, compact=compact,
                                us=us, model_order=model_order,
                                table=tuple(table), from_cache=False,
-                               buckets=bsig)
+                               buckets=bsig, failed=tuple(failed))
 
 
 def autotune_layer_plan(g: Graph, d_in: int, d_out: int, mode: str = "gcn",

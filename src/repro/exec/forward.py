@@ -582,6 +582,7 @@ def autotune_forward(g: Graph, specs: Sequence[LayerSpec], *,
 
     # 1. per-layer greedy — warms the cache the DP reads
     greedy = []
+    runnable = []
     for s, cands in zip(specs, cand_sets):
         rec_l = autotune_layer(g, s.d_in, s.d_out, s.mode, relu=s.relu,
                                bias=s.bias, candidates=cands,
@@ -589,16 +590,19 @@ def autotune_forward(g: Graph, specs: Sequence[LayerSpec], *,
         greedy.append(make_layer_cand(rec_l.order, rec_l.fuse, rec_l.backend,
                                       rec_l.bm, rec_l.compact,
                                       rec_l.buckets))
+        # neither DP may schedule a candidate that just failed to run
+        runnable.append(tuple(c for c in cands
+                              if tuple(c) not in rec_l.failed))
 
     # 2. candidate schedules
     schedules: Dict[str, Tuple[LayerCandidate, ...]] = {
         "greedy": tuple(greedy)}
-    warm = build_cost_oracle(g, specs, candidates=cand_sets,
+    warm = build_cost_oracle(g, specs, candidates=runnable,
                              cache_dir=cache_dir, use_cache=True)
     _, dp_configs = dp_schedule(warm)
     if tuple(dp_configs) not in schedules.values():
         schedules["dp"] = tuple(dp_configs)
-    cold = build_cost_oracle(g, specs, candidates=cand_sets,
+    cold = build_cost_oracle(g, specs, candidates=runnable,
                              cache_dir=cache_dir, use_cache=False)
     _, model_configs = dp_schedule(cold)
     if tuple(model_configs) not in schedules.values():

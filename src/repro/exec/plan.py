@@ -287,7 +287,7 @@ class GraphExecutionPlan:
     _width: Optional[int] = None
     _fn: Optional[Callable] = dataclasses.field(default=None, repr=False)
     buckets: str = ""                 # bucket signature, "" = single grid
-    _plan_bytes: int = 0              # bucketed: total per-bucket tile bytes
+    _plan_bytes: int = 0              # device bytes both directions ship
     _occupancy: list = dataclasses.field(default_factory=list, repr=False)
 
     @property
@@ -373,15 +373,21 @@ class GraphExecutionPlan:
                 "grid_size": self.grid_size,
                 "plan_bytes": self._plan_bytes,
             }
-        tm = traffic_model(self.ell, d)
         return {
             "mode": self.mode, "backend": self.backend,
             "compact": self.compact, "bm": self.bm, "bk": self.bk,
             "grid_size": self.grid_size,
             "padded_grid_size": self.ell.n_row_blocks * self.ell.width,
-            "plan_bytes": self.ell.storage_bytes() + self.ell_t.storage_bytes(),
-            **tm,
+            "plan_bytes": self._plan_bytes,
+            **self.traffic(d),
         }
+
+    def traffic(self, d: int) -> dict:
+        """:func:`traffic_model` of one forward launch at width ``d``, with
+        the adjacency counted at the tile dtype this plan ships."""
+        blocks = self._fwd.get("blocks")
+        return traffic_model(self.ell, d, adj_itemsize=(
+            None if blocks is None else blocks.dtype.itemsize))
 
 
 # ---------------------------------------------------------------------------
@@ -402,9 +408,27 @@ def _mode_scales(mode: str, g: Graph):
 
 
 def _pad_scale(s: np.ndarray, blocks: int, width: int) -> jnp.ndarray:
-    out = np.zeros(blocks * width, np.float32)
-    out[:s.shape[0]] = s
-    return jnp.asarray(out.reshape(blocks, width))
+    """Per-node scale as the kernels read it: a zero-padded
+    ``(blocks * width, 1)`` column, one ``(width, 1)`` block per tile."""
+    out = np.zeros((blocks * width, 1), np.float32)
+    out[:s.shape[0], 0] = s
+    return jnp.asarray(out)
+
+
+def _tile_dtype(ell: BlockEll, backend: str):
+    """Adjacency tile dtype a backend ships: the Pallas kernels take
+    unit-weight tiles as int8 (the TPU compiler casts int8, not uint8, to
+    float32); everything else computes on float32 tiles."""
+    return np.int8 if ell.implicit and backend == "pallas" else np.float32
+
+
+def _shipped_bytes(a) -> int:
+    """Bytes of the device arrays one direction of a plan holds."""
+    if isinstance(a, dict):
+        return sum(_shipped_bytes(v) for v in a.values())
+    if isinstance(a, list):
+        return sum(_shipped_bytes(v) for v in a)
+    return int(a.nbytes)
 
 
 def _side_arrays(ell: BlockEll, s_in: np.ndarray, s_out: np.ndarray,
@@ -416,18 +440,15 @@ def _side_arrays(ell: BlockEll, s_in: np.ndarray, s_out: np.ndarray,
         a["s_in2d"] = _pad_scale(s_in, C, ell.bk)
         a["s_out2d"] = _pad_scale(s_out, R, ell.bm)
     if compact:
-        comp = ell.compact(np.uint8 if ell.implicit and backend == "pallas"
-                           else np.float32)
+        comp = ell.compact(_tile_dtype(ell, backend))
         a["rows"] = jnp.asarray(comp.rows)
         a["cols"] = jnp.asarray(comp.cols)
-        a["blocks"] = jnp.asarray(comp.blocks if backend == "pallas"
-                                  else comp.blocks.astype(np.float32))
+        a["blocks"] = jnp.asarray(comp.blocks)
         node_active = np.repeat(comp.row_active, ell.bm)[:ell.num_nodes]
         a["node_active"] = jnp.asarray(node_active)
     else:
         a["block_cols"] = jnp.asarray(ell.block_cols)
-        dtype = np.uint8 if ell.implicit and backend == "pallas" else np.float32
-        a["blocks"] = jnp.asarray(ell.dense_blocks(dtype))
+        a["blocks"] = jnp.asarray(ell.dense_blocks(_tile_dtype(ell, backend)))
     return a
 
 
@@ -438,7 +459,7 @@ def _bucketed_side_arrays(g: Graph, scheme, s_in: np.ndarray,
     Destination nodes are partitioned by ``g``'s in-degrees (so the
     transpose direction re-buckets by its own skew) and remapped to a
     bucket-local contiguous row space; sources stay global.  Returns
-    ``(arrays, metas, plan_bytes)``.
+    ``(arrays, metas)``.
     """
     n = g.num_nodes
     valid = (g.edge_mask if g.edge_mask is not None
@@ -457,7 +478,6 @@ def _bucketed_side_arrays(g: Graph, scheme, s_in: np.ndarray,
 
     metas, buckets_a = [], []
     node_active = np.zeros(n, bool)
-    plan_bytes = 0
     for b, ((bm_b, _cut), idx) in enumerate(zip(scheme, idx_list)):
         if idx.size == 0:
             metas.append(BucketMeta(bm=bm_b, bk=bm_b, R=0, C=0, W=0,
@@ -468,7 +488,6 @@ def _bucketed_side_arrays(g: Graph, scheme, s_in: np.ndarray,
         ell_b = build_blockell_coo(
             src[sel], local_of[dst[sel]], w[sel], num_nodes=n,
             num_rows=int(idx.size), bm=bm_b, bk=bm_b, storage=storage)
-        plan_bytes += ell_b.storage_bytes()
         ab: Dict[str, jax.Array] = {"idx": jnp.asarray(idx.astype(np.int32))}
         if backend == "jnp":
             ab["block_cols"] = jnp.asarray(ell_b.block_cols)
@@ -477,7 +496,7 @@ def _bucketed_side_arrays(g: Graph, scheme, s_in: np.ndarray,
             node_active[idx] = True          # jnp computes every bucket row
             n_act = ell_b.n_active
         else:
-            comp = ell_b.compact(np.uint8 if ell_b.implicit else np.float32)
+            comp = ell_b.compact(_tile_dtype(ell_b, backend))
             ab["rows"] = jnp.asarray(comp.rows)
             ab["cols"] = jnp.asarray(comp.cols)
             ab["blocks"] = jnp.asarray(comp.blocks)
@@ -502,7 +521,7 @@ def _bucketed_side_arrays(g: Graph, scheme, s_in: np.ndarray,
     }
     if backend == "pallas":
         a["node_active"] = jnp.asarray(node_active)
-    return a, tuple(metas), int(plan_bytes)
+    return a, tuple(metas)
 
 
 def _coo_arrays(g: Graph, s_in: np.ndarray, s_out: np.ndarray,
@@ -574,7 +593,6 @@ def build_plan(g: Graph, mode: str = "gcn", *,
                         bm=bm, bk=bk, R=R, C=int(np.ceil(g.num_nodes / bk)),
                         n_active=n_active, n=g.num_nodes, interpret=interp)
 
-    plan_bytes = 0
     occupancy: list = []
     with obs.span("exec.plan.compile", cat="exec", backend=backend,
                   mode=mode, bm=bm, compact=compact, n=g.num_nodes,
@@ -582,12 +600,11 @@ def build_plan(g: Graph, mode: str = "gcn", *,
         if scheme:
             # each direction bucketed by ITS OWN in-degrees: per-bucket
             # transpose plans for the VJP
-            fwd, metas_f, bytes_f = _bucketed_side_arrays(
+            fwd, metas_f = _bucketed_side_arrays(
                 g_adj, scheme, s_in, s_out, backend, storage)
-            bwd, metas_b, bytes_b = _bucketed_side_arrays(
+            bwd, metas_b = _bucketed_side_arrays(
                 g_adj_t, scheme, s_out, s_in, backend, storage)
             ell = ell_t = None
-            plan_bytes = bytes_f + bytes_b
             meta_f = BucketedSideMeta(backend=backend, compact=compact,
                                       add_diag=add_diag, n=g.num_nodes,
                                       interpret=interp, buckets=metas_f)
@@ -600,8 +617,7 @@ def build_plan(g: Graph, mode: str = "gcn", *,
                           bm=occ["bm"]).set(occ["nodes"])
                 obs.gauge("exec.plan.bucket_edges", bucket=i,
                           bm=occ["bm"]).set(occ["edges"])
-            sp.set(n_active=sum(m.n_active for m in metas_f),
-                   plan_bytes=plan_bytes)
+            sp.set(n_active=sum(m.n_active for m in metas_f))
         elif backend == "coo":
             # the coo path never touches tiles: defer block-ELL to first
             # access
@@ -616,9 +632,9 @@ def build_plan(g: Graph, mode: str = "gcn", *,
             fwd = _side_arrays(ell, s_in, s_out, backend, compact)
             bwd = _side_arrays(ell_t, s_out, s_in, backend, compact)
             meta_f, meta_b = meta_for(ell.n_active), meta_for(ell_t.n_active)
-            sp.set(n_active=ell.n_active,
-                   plan_bytes=int(ell.storage_bytes()
-                                  + ell_t.storage_bytes()))
+            sp.set(n_active=ell.n_active)
+        plan_bytes = _shipped_bytes(fwd) + _shipped_bytes(bwd)
+        sp.set(plan_bytes=plan_bytes)
     obs.counter("exec.plan.compiles", backend=backend).inc()
     return GraphExecutionPlan(
         mode=mode, backend=backend, compact=compact, bm=bm, bk=bk,

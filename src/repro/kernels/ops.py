@@ -1,7 +1,8 @@
 """jit'd public wrappers: padding/layout plumbing + CPU-interpret fallback.
 
-On a real TPU runtime ``interpret=False`` compiles to Mosaic; this container
-is CPU-only, so the wrappers default to interpret mode there (detected once).
+On a TPU backend the wrappers compile the kernels with Mosaic
+(``interpret=False``); on any other backend they default to Pallas
+interpret mode.
 All callers go through these wrappers; tests sweep both paths' allclose
 against ref.py.
 """

@@ -53,6 +53,14 @@ Three variants:
 Destination blocks with zero active slots are never visited by the compacted
 grids; callers (repro.exec) fill those rows from the analytic diagonal term.
 
+Operand layouts the TPU compiler (Mosaic) accepts at every tile the plans
+use (16 to 512): the per-node scale vectors are (C*bk, 1) columns read
+through (bk, 1) blocks, so a block's last dimension equals the array's and
+its row count is a multiple of 8.  Unit-weight adjacency tiles arrive as
+int8 (Mosaic casts int8 but not uint8 to float32); weighted tiles as
+float32.  Kernels whose double-buffered blocks outgrow the default scoped
+VMEM (the 1536-wide first GCN layer at tile 512) raise the limit.
+
 Degree-binned multi-grid use (ISSUE 9): ``repro.exec.bucketing`` partitions
 destination NODES by in-degree and launches one compact kernel per bucket,
 each with its own square (bm, bk) tile.  A bucket's destination rows are
@@ -68,11 +76,41 @@ kernel.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+_VMEM_DEFAULT = 16 * 2 ** 20     # Mosaic's default scoped-VMEM limit
+_VMEM_CAP = 96 * 2 ** 20         # under the 128 MiB of a v5e core
+
+
+def _block_bytes(shape) -> int:
+    """VMEM bytes of one buffer of a block: the last two dims padded to the
+    (8, 128) tile at 4 bytes per element (an upper bound for int8)."""
+    shape = (1,) * (2 - len(shape)) + tuple(shape)
+    rows, lanes = -(-shape[-2] // 8) * 8, -(-shape[-1] // 128) * 128
+    return math.prod(shape[:-2]) * rows * lanes * 4
+
+
+def _call(kernel, grid_spec, out_shape, name: str, interpret: bool,
+          *operands):
+    """``pallas_call`` with a stable kernel name and, where the
+    double-buffered in/out blocks plus scratch outgrow the default scoped
+    VMEM, a raised ``vmem_limit_bytes``."""
+    blocks = [s.block_shape for s in grid_spec.in_specs]
+    blocks.append(grid_spec.out_specs.block_shape)
+    need = (2 * sum(_block_bytes(b) for b in blocks)
+            + sum(_block_bytes(s.shape) for s in grid_spec.scratch_shapes))
+    params = None
+    if need > _VMEM_DEFAULT * 3 // 4:
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=min(2 * need, _VMEM_CAP))
+    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                          name=name, compiler_params=params,
+                          interpret=interpret)(*operands)
 
 
 def _kernel(cols_ref, adj_ref, x_ref, o_ref):
@@ -109,12 +147,8 @@ def spmm_blockell(block_cols: jax.Array, blocks: jax.Array, x: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, d), lambda r, w, cols: (r, 0)),
     )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R * bm, d), x.dtype),
-        interpret=interpret,
-    )(block_cols, blocks, x)
+    return _call(_kernel, grid_spec, jax.ShapeDtypeStruct((R * bm, d), x.dtype),
+                 "spmm_blockell", interpret, block_cols, blocks, x)
 
 
 # ---------------------------------------------------------------------------
@@ -132,20 +166,20 @@ def _make_fused_kernel(W: int, add_diag: bool):
         @pl.when(w == 0)
         def _init():
             if add_diag:
-                o_ref[...] = xd_ref[...] * sind_ref[0][:, None]
+                o_ref[...] = xd_ref[...] * sind_ref[...]
             else:
                 o_ref[...] = jnp.zeros_like(o_ref)
 
         @pl.when(cols_ref[r, w] >= 0)
         def _accum():
-            xs = x_ref[...] * sin_ref[0][:, None]
+            xs = x_ref[...] * sin_ref[...]
             o_ref[...] += jnp.dot(adj_ref[0, 0].astype(jnp.float32), xs,
                                   preferred_element_type=jnp.float32
                                   ).astype(o_ref.dtype)
 
         @pl.when(w == W - 1)
         def _scale():
-            o_ref[...] *= sout_ref[0][:, None]
+            o_ref[...] *= sout_ref[...]
     return kernel
 
 
@@ -155,7 +189,7 @@ def spmm_blockell_fused(block_cols: jax.Array, blocks: jax.Array,
                         x: jax.Array, s_in: jax.Array, s_out: jax.Array,
                         *, bm: int, bk: int, add_diag: bool,
                         interpret: bool = False) -> jax.Array:
-    """Padded fused SpMM.  s_in: (C, bk); s_out: (R, bm); x: (C*bk, d).
+    """Padded fused SpMM.  s_in: (C*bk, 1); s_out: (R*bm, 1); x: (C*bk, d).
     With ``add_diag`` (requires bm == bk so a row tile of x is a block tile)
     the self-loop term s_in ⊙ x seeds the accumulator.  Returns (R*bm, d).
     """
@@ -167,14 +201,14 @@ def spmm_blockell_fused(block_cols: jax.Array, blocks: jax.Array,
         pl.BlockSpec((1, 1, bm, bk), lambda r, w, cols: (r, w, 0, 0)),
         pl.BlockSpec((bk, d),
                      lambda r, w, cols: (jnp.maximum(cols[r, w], 0), 0)),
-        pl.BlockSpec((1, bk),
+        pl.BlockSpec((bk, 1),
                      lambda r, w, cols: (jnp.maximum(cols[r, w], 0), 0)),
-        pl.BlockSpec((1, bm), lambda r, w, cols: (r, 0)),
+        pl.BlockSpec((bm, 1), lambda r, w, cols: (r, 0)),
     ]
     operands = [blocks, x, s_in, s_out]
     if add_diag:
         in_specs += [pl.BlockSpec((bk, d), lambda r, w, cols: (r, 0)),
-                     pl.BlockSpec((1, bk), lambda r, w, cols: (r, 0))]
+                     pl.BlockSpec((bk, 1), lambda r, w, cols: (r, 0))]
         operands += [x, s_in]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -182,12 +216,9 @@ def spmm_blockell_fused(block_cols: jax.Array, blocks: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, d), lambda r, w, cols: (r, 0)),
     )
-    return pl.pallas_call(
-        _make_fused_kernel(W, add_diag),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R * bm, d), x.dtype),
-        interpret=interpret,
-    )(block_cols, *operands)
+    return _call(_make_fused_kernel(W, add_diag), grid_spec,
+                 jax.ShapeDtypeStruct((R * bm, d), x.dtype),
+                 "spmm_blockell_fused", interpret, block_cols, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -208,18 +239,18 @@ def _make_compact_kernel(n_active: int, add_diag: bool):
         @pl.when(first)
         def _init():
             if add_diag:
-                o_ref[...] = xd_ref[...] * sind_ref[0][:, None]
+                o_ref[...] = xd_ref[...] * sind_ref[...]
             else:
                 o_ref[...] = jnp.zeros_like(o_ref)
 
-        xs = x_ref[...] * sin_ref[0][:, None]
+        xs = x_ref[...] * sin_ref[...]
         o_ref[...] += jnp.dot(adj_ref[0].astype(jnp.float32), xs,
                               preferred_element_type=jnp.float32
                               ).astype(o_ref.dtype)
 
         @pl.when(last)
         def _scale():
-            o_ref[...] *= sout_ref[0][:, None]
+            o_ref[...] *= sout_ref[...]
     return kernel
 
 
@@ -237,8 +268,9 @@ def spmm_blockell_compact(rows: jax.Array, cols: jax.Array,
     """Slot-compacted fused SpMM: the grid is exactly ``n_active`` steps.
 
     rows / cols: (n_active,) int32 sorted row-major (core.BlockCompaction);
-    blocks: (n_active, bm, bk); x: (C*bk, d); s_in: (C, bk); s_out: (R, bm).
-    ``x_diag`` (R*bm, d) / ``s_in_diag`` (R, bm) override the ``add_diag``
+    blocks: (n_active, bm, bk); x: (C*bk, d); s_in: (C*bk, 1);
+    s_out: (R*bm, 1).
+    ``x_diag`` (R*bm, d) / ``s_in_diag`` (R*bm, 1) override the ``add_diag``
     self-term operands when destination rows are remapped (degree-bucketed
     sub-grids); default is the unbucketed behavior where destination row
     tiles are slices of the global x / s_in.
@@ -255,13 +287,13 @@ def spmm_blockell_compact(rows: jax.Array, cols: jax.Array,
     in_specs = [
         pl.BlockSpec((1, bm, bk), lambda i, rows, cols: (i, 0, 0)),
         pl.BlockSpec((bk, d), lambda i, rows, cols: (cols[i], 0)),
-        pl.BlockSpec((1, bk), lambda i, rows, cols: (cols[i], 0)),
-        pl.BlockSpec((1, bm), lambda i, rows, cols: (rows[i], 0)),
+        pl.BlockSpec((bk, 1), lambda i, rows, cols: (cols[i], 0)),
+        pl.BlockSpec((bm, 1), lambda i, rows, cols: (rows[i], 0)),
     ]
     operands = [blocks, x, s_in, s_out]
     if add_diag:
         in_specs += [pl.BlockSpec((bk, d), lambda i, rows, cols: (rows[i], 0)),
-                     pl.BlockSpec((1, bk), lambda i, rows, cols: (rows[i], 0))]
+                     pl.BlockSpec((bk, 1), lambda i, rows, cols: (rows[i], 0))]
         operands += [x if x_diag is None else x_diag,
                      s_in if s_in_diag is None else s_in_diag]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -270,12 +302,9 @@ def spmm_blockell_compact(rows: jax.Array, cols: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, d), lambda i, rows, cols: (rows[i], 0)),
     )
-    return pl.pallas_call(
-        _make_compact_kernel(n_active, add_diag),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R * bm, d), x.dtype),
-        interpret=interpret,
-    )(rows, cols, *operands)
+    return _call(_make_compact_kernel(n_active, add_diag), grid_spec,
+                 jax.ShapeDtypeStruct((R * bm, d), x.dtype),
+                 "spmm_blockell_compact", interpret, rows, cols, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +317,7 @@ def _layer_epilogue(acc_ref, sout_ref, w_ref, bias_ref, o_ref, relu,
     With ``wself_ref`` the destination-row x tile contributes a second MXU
     product (optionally scaled by the SMEM ``self_coeff`` scalar):
     two-W form  out = (s_out ⊙ acc) @ W_nbr + (c ⊙ x_tile) @ W_self + b."""
-    y = acc_ref[...] * sout_ref[0][:, None]
+    y = acc_ref[...] * sout_ref[...]
     out = jnp.dot(y, w_ref[...], preferred_element_type=jnp.float32)
     if wself_ref is not None:
         xs = xself_ref[...]
@@ -321,13 +350,13 @@ def _make_update_kernel(n_slots: int, add_diag: bool, has_bias: bool,
         @pl.when(w == 0)
         def _init():
             if add_diag:
-                acc_ref[...] = xd_ref[...] * sind_ref[0][:, None]
+                acc_ref[...] = xd_ref[...] * sind_ref[...]
             else:
                 acc_ref[...] = jnp.zeros_like(acc_ref)
 
         @pl.when(cols_ref[r, w] >= 0)
         def _accum():
-            xs = x_ref[...] * sin_ref[0][:, None]
+            xs = x_ref[...] * sin_ref[...]
             acc_ref[...] += jnp.dot(adj_ref[0, 0].astype(jnp.float32), xs,
                                     preferred_element_type=jnp.float32)
 
@@ -369,9 +398,9 @@ def spmm_blockell_update(block_cols: jax.Array, blocks: jax.Array,
         pl.BlockSpec((1, 1, bm, bk), lambda r, s, cols: (r, s, 0, 0)),
         pl.BlockSpec((bk, d_in),
                      lambda r, s, cols: (jnp.maximum(cols[r, s], 0), 0)),
-        pl.BlockSpec((1, bk),
+        pl.BlockSpec((bk, 1),
                      lambda r, s, cols: (jnp.maximum(cols[r, s], 0), 0)),
-        pl.BlockSpec((1, bm), lambda r, s, cols: (r, 0)),
+        pl.BlockSpec((bm, 1), lambda r, s, cols: (r, 0)),
         pl.BlockSpec((d_in, d_out), lambda r, s, cols: (0, 0)),
     ]
     operands = [blocks, x, s_in, s_out, w]
@@ -388,7 +417,7 @@ def spmm_blockell_update(block_cols: jax.Array, blocks: jax.Array,
             operands.append(self_coeff)
     if add_diag:
         in_specs += [pl.BlockSpec((bk, d_in), lambda r, s, cols: (r, 0)),
-                     pl.BlockSpec((1, bk), lambda r, s, cols: (r, 0))]
+                     pl.BlockSpec((bk, 1), lambda r, s, cols: (r, 0))]
         operands += [x, s_in]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -397,13 +426,11 @@ def spmm_blockell_update(block_cols: jax.Array, blocks: jax.Array,
         out_specs=pl.BlockSpec((bm, d_out), lambda r, s, cols: (r, 0)),
         scratch_shapes=[pltpu.VMEM((bm, d_in), jnp.float32)],
     )
-    return pl.pallas_call(
-        _make_update_kernel(W, add_diag, bias is not None, relu,
-                            w_self is not None, self_coeff is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R * bm, d_out), x.dtype),
-        interpret=interpret,
-    )(block_cols, *operands)
+    return _call(_make_update_kernel(W, add_diag, bias is not None, relu,
+                                     w_self is not None,
+                                     self_coeff is not None),
+                 grid_spec, jax.ShapeDtypeStruct((R * bm, d_out), x.dtype),
+                 "spmm_blockell_update", interpret, block_cols, *operands)
 
 
 def _make_update_compact_kernel(n_active: int, add_diag: bool, has_bias: bool,
@@ -428,11 +455,11 @@ def _make_update_compact_kernel(n_active: int, add_diag: bool, has_bias: bool,
         @pl.when(first)
         def _init():
             if add_diag:
-                acc_ref[...] = xd_ref[...] * sind_ref[0][:, None]
+                acc_ref[...] = xd_ref[...] * sind_ref[...]
             else:
                 acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        xs = x_ref[...] * sin_ref[0][:, None]
+        xs = x_ref[...] * sin_ref[...]
         acc_ref[...] += jnp.dot(adj_ref[0].astype(jnp.float32), xs,
                                 preferred_element_type=jnp.float32)
 
@@ -460,7 +487,7 @@ def spmm_blockell_update_compact(rows: jax.Array, cols: jax.Array,
     destination block's last step runs the W-update epilogue before its one
     (bm, d_out) store.  ``w_self``/``self_coeff`` add the two-W self term
     exactly as in :func:`spmm_blockell_update`.  ``x_self`` (R*bm, d_in) /
-    ``x_diag`` (R*bm, d_in) / ``s_in_diag`` (R, bm) override the
+    ``x_diag`` (R*bm, d_in) / ``s_in_diag`` (R*bm, 1) override the
     destination-row-indexed operands for degree-bucketed sub-grids whose
     destination rows are remapped; defaults slice the global x / s_in.
     Rows whose destination block has no active slot are left unwritten —
@@ -480,8 +507,8 @@ def spmm_blockell_update_compact(rows: jax.Array, cols: jax.Array,
     in_specs = [
         pl.BlockSpec((1, bm, bk), lambda i, rows, cols: (i, 0, 0)),
         pl.BlockSpec((bk, d_in), lambda i, rows, cols: (cols[i], 0)),
-        pl.BlockSpec((1, bk), lambda i, rows, cols: (cols[i], 0)),
-        pl.BlockSpec((1, bm), lambda i, rows, cols: (rows[i], 0)),
+        pl.BlockSpec((bk, 1), lambda i, rows, cols: (cols[i], 0)),
+        pl.BlockSpec((bm, 1), lambda i, rows, cols: (rows[i], 0)),
         pl.BlockSpec((d_in, d_out), lambda i, rows, cols: (0, 0)),
     ]
     operands = [blocks, x, s_in, s_out, w]
@@ -503,7 +530,7 @@ def spmm_blockell_update_compact(rows: jax.Array, cols: jax.Array,
     if add_diag:
         in_specs += [pl.BlockSpec((bk, d_in),
                                   lambda i, rows, cols: (rows[i], 0)),
-                     pl.BlockSpec((1, bk), lambda i, rows, cols: (rows[i], 0))]
+                     pl.BlockSpec((bk, 1), lambda i, rows, cols: (rows[i], 0))]
         operands += [x if x_diag is None else x_diag,
                      s_in if s_in_diag is None else s_in_diag]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -513,11 +540,10 @@ def spmm_blockell_update_compact(rows: jax.Array, cols: jax.Array,
         out_specs=pl.BlockSpec((bm, d_out), lambda i, rows, cols: (rows[i], 0)),
         scratch_shapes=[pltpu.VMEM((bm, d_in), jnp.float32)],
     )
-    return pl.pallas_call(
-        _make_update_compact_kernel(n_active, add_diag, bias is not None,
-                                    relu, w_self is not None,
-                                    self_coeff is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R * bm, d_out), x.dtype),
-        interpret=interpret,
-    )(rows, cols, *operands)
+    return _call(_make_update_compact_kernel(n_active, add_diag,
+                                             bias is not None, relu,
+                                             w_self is not None,
+                                             self_coeff is not None),
+                 grid_spec, jax.ShapeDtypeStruct((R * bm, d_out), x.dtype),
+                 "spmm_blockell_update_compact", interpret, rows, cols,
+                 *operands)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..models import lm_init, lm_prefill, lm_decode_step
+from .runtime import enable_compile_cache, float32_matmuls
 
 
 def serve_lm(args) -> None:
@@ -57,7 +58,8 @@ def serve_lm(args) -> None:
     seq = np.concatenate([np.asarray(t) for t in out_tokens], axis=1)
     print("generated:", seq[0].tolist())
     print(f"{args.tokens} tokens x {args.batch} batch in {dt:.2f}s "
-          f"({args.tokens * args.batch / dt:.1f} tok/s on CPU)")
+          f"({args.tokens * args.batch / dt:.1f} tok/s on "
+          f"{jax.devices()[0].platform})")
 
 
 def _load_graph(name: str, scale: float):
@@ -72,7 +74,7 @@ def _load_graph(name: str, scale: float):
                      "(choices: cora, citeseer-s, reddit)")
 
 
-def serve_graph(args) -> None:
+def serve_graph(args):
     from ..core import identity_order, minhash_reorder
     from ..serve import (EmbeddingCache, MicroBatcher, ServeEngine,
                          make_session, zipfian_trace)
@@ -107,6 +109,7 @@ def serve_graph(args) -> None:
               f"{'OK' if ok else 'MISMATCH'}")
         if not ok:
             raise SystemExit(1)
+    return rep
 
 
 def main(argv=None):
@@ -143,12 +146,13 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.summary and not (args.metrics_out or args.trace):
         ap.error("--summary needs --metrics-out and/or --trace")
+    enable_compile_cache()
     try:
-        with obs.observed_run(args.metrics_out, args.trace):
+        with obs.observed_run(args.metrics_out, args.trace), \
+                float32_matmuls():
             if args.graph is not None:
-                serve_graph(args)
-            else:
-                serve_lm(args)
+                return serve_graph(args)
+            serve_lm(args)
     finally:
         if args.summary:
             from ..obs import summary as _summary

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..configs import get
+from .runtime import enable_compile_cache, float32_matmuls
 from ..train import adam, fit, lm_token_batches, recsys_batches
 
 
@@ -30,26 +31,41 @@ def lm_reduced_driver(arch: str, steps: int, ckpt: str):
                lm_token_batches(cfg.vocab, 4, 64), steps=steps, ckpt_dir=ckpt)
 
 
-def gnn_driver(arch: str, steps: int, ckpt: str, executor: str = "auto"):
+def gnn_graph():
+    """The graph every GNN run trains on: Cora-shaped, LSH-reordered."""
     from ..graph import cora_like
     from ..core import minhash_reorder
+    g = cora_like()
+    return g.permute(minhash_reorder(g))
+
+
+def gnn_driver(arch: str, steps: int, ckpt: str, executor: str = "auto",
+               schedule=None):
+    """Train a GNN arch on :func:`gnn_graph`.  For GCN, ``schedule`` (one
+    ``repro.exec`` layer candidate per layer) builds exactly that
+    whole-forward schedule instead of choosing one."""
     spec = get(arch)
     bundle = spec.bundle()
-    g = cora_like().permute(minhash_reorder(cora_like()))
+    g = gnn_graph()
     exec_plan = None
     layer_plans = None
-    if bundle.arch == "gcn" and executor in ("auto", "forward", "fused"):
+    if bundle.arch == "gcn" and (schedule is not None or executor in
+                                 ("auto", "forward", "fused")):
         # default hot path: WHOLE-FORWARD scheduling — the repro.exec DP
         # picks every layer's (order, fuse, backend, bm, compact) jointly.
         # "auto"/"forward" additionally race the DP schedule against the
         # per-layer-greedy and cold-model schedules as measured whole-chain
         # fwd+bwd passes and cache the verdict on disk; "fused" trusts the
         # DP over the cache/FLOP-byte model without measuring
-        from ..exec import autotune_forward, plan_forward, gcn_chain
+        from ..exec import (autotune_forward, build_forward_plan, gcn_chain,
+                            plan_forward)
         dims = [g.node_feat.shape[1], *bundle.model_kw["hidden"],
                 bundle.n_classes]
         specs = gcn_chain(dims)
-        if executor in ("auto", "forward"):
+        if schedule is not None:
+            layer_plans = build_forward_plan(g, specs, schedule)
+            print("forward schedule: explicit")
+        elif executor in ("auto", "forward"):
             layer_plans, rec = autotune_forward(g, specs)
             obs.counter("exec.forward.verdict", source=rec.source).inc()
             obs.gauge("exec.forward.verdict_us").set(rec.us)
@@ -65,7 +81,8 @@ def gnn_driver(arch: str, steps: int, ckpt: str, executor: str = "auto"):
         for i, (s, lp) in enumerate(zip(specs, layer_plans.layers)):
             print(f"layer {i} ({s.d_in}->{s.d_out}): order={lp.order} "
                   f"fuse={lp.fuse} {lp.backend} bm={lp.gplan.bm} "
-                  f"compact={lp.gplan.compact}")
+                  f"compact={lp.gplan.compact} "
+                  f"buckets={lp.gplan.buckets or '-'}")
     elif bundle.arch == "gcn" and executor == "blockell":
         # the PR 3 path: fused aggregation, separate update matmul
         from ..exec import build_plan
@@ -82,7 +99,6 @@ def gnn_driver(arch: str, steps: int, ckpt: str, executor: str = "auto"):
             executor="blockell" if exec_plan is not None else "segment",
             exec_plan=exec_plan)
     params = bundle.init_params(jax.random.PRNGKey(0), g.node_feat.shape[1])
-    import numpy as np
     deg = g.in_degrees().astype(np.float32) + 1.0
     batch = {"src": jnp.asarray(g.src), "dst": jnp.asarray(g.dst),
              "edge_mask": jnp.ones(g.num_edges, bool),
@@ -150,8 +166,10 @@ def main(argv=None):
     if args.summary and not (args.metrics_out or args.trace):
         ap.error("--summary needs --metrics-out and/or --trace")
     spec = get(args.arch)
+    enable_compile_cache()
     try:
-        with obs.observed_run(args.metrics_out, args.trace):
+        with obs.observed_run(args.metrics_out, args.trace), \
+                float32_matmuls():
             if args.dist:
                 if spec.family != "gnn":
                     ap.error(f"--dist supports GNN archs; {args.arch} is "
@@ -167,7 +185,7 @@ def main(argv=None):
                 losses = res["losses"]
                 print(f"{args.arch} [dist]: {len(losses)} steps, loss "
                       f"{losses[0]:.4f} -> {losses[-1]:.4f}")
-                return
+                return res
             driver = {"lm": lm_reduced_driver, "gnn": gnn_driver,
                       "recsys": recsys_driver}[spec.family]
             if spec.family == "gnn":
@@ -178,6 +196,7 @@ def main(argv=None):
             print(f"{args.arch}: {res.steps} steps, loss "
                   f"{res.losses[0]:.4f} -> {res.losses[-1]:.4f}, "
                   f"{res.wall_time:.1f}s, stragglers={res.straggler_flags}")
+            return res
     finally:
         if args.summary:
             from ..obs import summary as _summary

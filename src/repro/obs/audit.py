@@ -466,16 +466,17 @@ def render_report(table: dict, findings: List[dict],
     if table["n_obs"]:
         lines.append(f"global measured/model ratio: "
                      f"{table['global_ratio']:.4g} us per byte-equivalent")
-        try:                      # roofline context (target-chip units)
-            from ..roofline import hw
-            bps = hw.implied_bandwidth(table["global_ratio"])
-            frac = hw.hbm_fraction(table["global_ratio"])
-            lines.append(f"  implied {bps / 1e9:.2f} GB-equiv/s vs the "
-                         f"TARGET chip's {hw.HBM_BW / 1e9:.0f} GB/s HBM "
-                         f"roofline ({frac:.1%}; CPU hosts are expected to "
-                         "sit far below it)")
-        except Exception:
-            pass
+        from ..roofline import hw
+        try:                      # roofline context, this device's peaks
+            chip = hw.peaks()
+        except KeyError:          # no listed peaks (a CPU host)
+            chip = None
+        if chip is not None:
+            ratio = table["global_ratio"]
+            lines.append(f"  implied {hw.implied_bandwidth(ratio) / 1e9:.2f}"
+                         f" GB-equiv/s vs this chip's "
+                         f"{chip.hbm_bw / 1e9:.0f} GB/s HBM roofline "
+                         f"({hw.hbm_fraction(ratio):.1%})")
         lines.append("")
         lines.append("per-class calibration (cold DP consumes 'ratio'):")
         lines.append(_fmt_table(

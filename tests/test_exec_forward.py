@@ -441,6 +441,34 @@ def test_autotune_forward_round_trip(tmp_path):
                for k in entries)
 
 
+def test_autotune_forward_never_schedules_a_failed_candidate(tmp_path,
+                                                            capsys):
+    """A Pallas candidate that fails to run when raced is counted per
+    backend and printed with its error, and no schedule in the whole-forward
+    race contains it — the cold FLOP/byte DP credits fusion, so without the
+    exclusion it would schedule the failed fused layer."""
+    from repro import obs
+    from repro.chaos import Fault, FaultPlan, armed
+    g = _random_graph(220, 1300, seed=6)
+    specs = gcn_chain([16, 64, 64])     # the cold DP fuses both layers
+    fused = ("aggregate_first", True, "pallas", 128, True)
+
+    def failed_count():
+        return sum(v for k, v in obs.snapshot()["counters"].items()
+                   if k.startswith("exec.autotune.failed{")
+                   and "backend=pallas" in k)
+
+    before = failed_count()
+    with armed(FaultPlan.of(Fault("exec.pallas_launch", "kernel_launch",
+                                  count=10 ** 6))):
+        fp, rec = autotune_forward(g, specs, candidates=[COO_CANDS + [fused]],
+                                   cache_dir=str(tmp_path), iters=1)
+    assert failed_count() - before == len(specs)
+    assert "autotune: candidate" in capsys.readouterr().out
+    assert all(fused not in cfgs for _, cfgs in rec.schedules)
+    assert all(lp.backend == "coo" for lp in fp.layers)
+
+
 # ============================================================ cache prune
 def test_prune_cache_keeps_most_recent(tmp_path):
     g = _random_graph(200, 1000, seed=7)

@@ -315,6 +315,24 @@ def test_render_report_and_cli(tmp_path):
     assert not os.path.exists(calibration_path(cache))
 
 
+def test_roofline_peaks_keyed_by_device_kind(monkeypatch):
+    from repro.roofline import hw
+    v5e = hw.peaks("TPU v5 lite")
+    assert v5e.hbm_bw == 819e9 and v5e.flops_bf16 == 197e12
+    # 1e6 / 819e9 us per byte-equivalent is exactly the HBM roofline
+    assert hw.hbm_fraction(1e6 / 819e9, "TPU v5 lite") == pytest.approx(1.0)
+    for kind in ("cpu", "TPU v4"):
+        with pytest.raises(KeyError, match="no peak numbers"):
+            hw.hbm_fraction(1.0, kind)
+    # on a host without listed peaks the report omits the roofline line
+    monkeypatch.setattr(hw, "peaks", lambda kind=None: hw.PEAKS["TPU v5 lite"])
+    table = {"device_sig": "tpu", "n_obs": 1, "global_ratio": 1e6 / 819e9,
+             "classes": {}, "groups": {}}
+    assert "819 GB/s HBM roofline (100.0%)" in render_report(table, [])
+    monkeypatch.undo()
+    assert "HBM roofline" not in render_report(table, [])
+
+
 # ================================================================ summary
 def test_summary_renders_metrics_and_trace(tmp_path):
     from repro.obs import summary
