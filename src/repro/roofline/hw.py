@@ -30,8 +30,7 @@ PEAKS = {
                "16 GB HBM2 at 819 GB/s, ICI 1600 Gbps over 4 links"),
 }
 
-TARGET_KIND = "TPU v5 lite"   # the chip the production-mesh analysis targets
-_TARGET = PEAKS[TARGET_KIND]
+_TARGET = PEAKS["TPU v5 lite"]   # the chip the production-mesh analysis targets
 PEAK_FLOPS_BF16 = _TARGET.flops_bf16
 HBM_BW = _TARGET.hbm_bw
 ICI_BW = _TARGET.ici_bw
