@@ -105,21 +105,23 @@ class BucketedSideMeta(NamedTuple):
 # ---------------------------------------------------------------------------
 def _run_side(meta, a: Dict[str, jax.Array], x: jax.Array
               ) -> jax.Array:
-    if isinstance(meta, BucketedSideMeta):
-        return _run_bucketed(meta, a, x)
-    if meta.backend == "coo":
-        y = jax.ops.segment_sum(x[a["src"]] * a["w"][:, None], a["dst"],
-                                num_segments=meta.n)
-        if meta.add_diag:
-            # self-loop as an elementwise FMA (s_out*s_in per node) — far
-            # cheaper than scattering N extra diagonal edges
-            y = y + a["dvec"][:, None] * x
-        return y
-    if meta.backend == "jnp":
-        return _jnp_blocks(meta, a, x)
-    if meta.backend == "pallas":
-        return _pallas_blocks(meta, a, x)
-    raise ValueError(meta.backend)
+    """One aggregation, traced under the ``aggregate`` name scope."""
+    with jax.named_scope("aggregate"):
+        if isinstance(meta, BucketedSideMeta):
+            return _run_bucketed(meta, a, x)
+        if meta.backend == "coo":
+            y = jax.ops.segment_sum(x[a["src"]] * a["w"][:, None], a["dst"],
+                                    num_segments=meta.n)
+            if meta.add_diag:
+                # self-loop as an elementwise FMA (s_out*s_in per node) —
+                # far cheaper than scattering N extra diagonal edges
+                y = y + a["dvec"][:, None] * x
+            return y
+        if meta.backend == "jnp":
+            return _jnp_blocks(meta, a, x)
+        if meta.backend == "pallas":
+            return _pallas_blocks(meta, a, x)
+        raise ValueError(meta.backend)
 
 
 def _jnp_blocks(meta: SideMeta, a: Dict[str, jax.Array], x: jax.Array
@@ -888,22 +890,34 @@ class LayerExecutionPlan:
                 y = y + b
             return jnp.maximum(y, 0.0) if relu else y
 
+        # name scopes: ``aggregate`` for each aggregation (``_run_side``)
+        # and for the fused kernel, which also updates; ``update`` for the
+        # weight products, self half, bias and ReLU around them
         def forward(x, w, b, ws, c):
             if fuse:
-                return _pallas_layer(meta_f, af, x, w, b, relu, ws, c)
-            y = (_run_side(meta_f, af, x) @ w if order == "aggregate_first"
-                 else _run_side(meta_f, af, x @ w))
-            if ws is not None:
-                y = y + _self_term(x, ws, c)
-            return post(y, b)
+                with jax.named_scope("aggregate"):
+                    return _pallas_layer(meta_f, af, x, w, b, relu, ws, c)
+            if order == "aggregate_first":
+                agg = _run_side(meta_f, af, x)
+                with jax.named_scope("update"):
+                    y = agg @ w
+            else:
+                with jax.named_scope("update"):
+                    xw = x @ w
+                y = _run_side(meta_f, af, xw)
+            with jax.named_scope("update"):
+                if ws is not None:
+                    y = y + _self_term(x, ws, c)
+                return post(y, b)
 
         def fwd_core(x, w, b, ws, c):
             if agg_residual:
                 agg = _run_side(meta_f, af, x)
-                y = agg @ w
-                if ws is not None:
-                    y = y + _self_term(x, ws, c)
-                y = post(y, b)
+                with jax.named_scope("update"):
+                    y = agg @ w
+                    if ws is not None:
+                        y = y + _self_term(x, ws, c)
+                    y = post(y, b)
                 # the self half's dW_self/dc need x; without it the agg
                 # residual alone suffices
                 return y, (agg, x if ws is not None else None, w, ws, c, y)
@@ -913,28 +927,33 @@ class LayerExecutionPlan:
         def bwd_core(res, g):
             agg, x, w, ws, c, y = res
             if relu:
-                g = jnp.where(y > 0, g, 0.0)
+                with jax.named_scope("update"):
+                    g = jnp.where(y > 0, g, 0.0)
             if agg is not None:
                 # agg = M x: dx = Mᵀ (ḡ Wᵀ) runs at width d_in and
                 # dW = aggᵀ ḡ reuses the forward's aggregation
-                dx = _run_side(meta_b, ab, g @ w.T)
-                dw = jnp.einsum("nd,ne->de", agg, g)
+                with jax.named_scope("update"):
+                    gw = g @ w.T
+                    dw = jnp.einsum("nd,ne->de", agg, g)
+                dx = _run_side(meta_b, ab, gw)
             else:
                 # h = Mᵀ ḡ runs at width d_out, dW = Σ_v x_v ⊗ h_v
                 h = _run_side(meta_b, ab, g)
-                dx = h @ w.T
-                dw = jnp.einsum("nd,ne->de", x, h)
+                with jax.named_scope("update"):
+                    dx = h @ w.T
+                    dw = jnp.einsum("nd,ne->de", x, h)
             dws = dc = None
             if ws is not None:
-                xtg = jnp.einsum("nd,ne->de", x, g)
-                if c is not None:
-                    cs = jnp.reshape(c, ())
-                    dx = dx + cs * (g @ ws.T)
-                    dws = cs * xtg
-                    dc = jnp.reshape(jnp.vdot(ws, xtg), jnp.shape(c))
-                else:
-                    dx = dx + g @ ws.T
-                    dws = xtg
+                with jax.named_scope("update"):
+                    xtg = jnp.einsum("nd,ne->de", x, g)
+                    if c is not None:
+                        cs = jnp.reshape(c, ())
+                        dx = dx + cs * (g @ ws.T)
+                        dws = cs * xtg
+                        dc = jnp.reshape(jnp.vdot(ws, xtg), jnp.shape(c))
+                    else:
+                        dx = dx + g @ ws.T
+                        dws = xtg
             return g, dx, dw, dws, dc
 
         # one fixed-arity custom_vjp covers every optional-operand combo:

@@ -37,7 +37,8 @@ def _aggregate(x, graph, executor: str, plan=None, ell=None):
     ``executor="blockell"`` with a ``repro.exec.GraphExecutionPlan`` (mode
     "gcn") runs the whole chain — source scaling, SpMM, self-loop,
     destination scaling — as ONE fused, differentiable launch; the legacy
-    dict-of-arrays form keeps the old unfused jnp tile path.
+    dict-of-arrays form keeps the old unfused jnp tile path.  Every path
+    traces under the ``aggregate`` name scope (the plan's own).
     """
     if executor == "blockell" and hasattr(ell, "apply"):
         if ell.mode != "gcn":
@@ -47,22 +48,23 @@ def _aggregate(x, graph, executor: str, plan=None, ell=None):
             raise ValueError(f"plan compiled for {ell.num_nodes} nodes but "
                              f"x has {x.shape[0]} rows (wrong graph?)")
         return ell.apply(x)                 # fused A_hat @ x, custom VJP
-    deg = graph["deg"]                      # (N,) in-degree + 1 (self loop)
-    inv_sqrt = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
-    xs = x * inv_sqrt[:, None]              # source scaling
-    if executor == "segment":
-        agg = segment_aggregate(xs, graph["src"], graph["dst"],
-                                x.shape[0], op="sum",
-                                edge_mask=graph.get("edge_mask"))
-    elif executor == "shared":
-        agg = shared_aggregate(xs, plan, op="sum")
-    elif executor == "blockell":
-        agg = blockell_matmul(ell["block_cols"], ell["blocks"], xs,
-                              ell["bm"], ell["bk"])
-    else:
-        raise ValueError(executor)
-    agg = agg + xs                          # self loop
-    return agg * inv_sqrt[:, None]          # destination scaling
+    with jax.named_scope("aggregate"):
+        deg = graph["deg"]                  # (N,) in-degree + 1 (self loop)
+        inv_sqrt = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
+        xs = x * inv_sqrt[:, None]          # source scaling
+        if executor == "segment":
+            agg = segment_aggregate(xs, graph["src"], graph["dst"],
+                                    x.shape[0], op="sum",
+                                    edge_mask=graph.get("edge_mask"))
+        elif executor == "shared":
+            agg = shared_aggregate(xs, plan, op="sum")
+        elif executor == "blockell":
+            agg = blockell_matmul(ell["block_cols"], ell["blocks"], xs,
+                                  ell["bm"], ell["bk"])
+        else:
+            raise ValueError(executor)
+        agg = agg + xs                      # self loop
+        return agg * inv_sqrt[:, None]      # destination scaling
 
 
 def _layer_plans_for(ell, params, mode: str):
@@ -88,6 +90,10 @@ def _layer_plans_for(ell, params, mode: str):
 def gcn_apply(params, x: jax.Array, graph: Dict[str, Any],
               executor: str = "segment", plan=None, ell=None,
               act=jax.nn.relu) -> jax.Array:
+    """Layer ``i`` traces under the name scope ``layer{i}``: its
+    aggregation under ``aggregate``, its weight product, bias and activation
+    under ``update`` (a fused layer kernel, which does both in one launch,
+    under ``aggregate``).  Backward ops inherit the scopes."""
     h = x
     n_layers = len(params["layers"])
     if executor == "fused":
@@ -102,18 +108,24 @@ def gcn_apply(params, x: jax.Array, graph: Dict[str, Any],
                           "falling back to the per-layer graph-plan path "
                           "for this activation", stacklevel=2)
             for i, (p, lp) in enumerate(zip(params["layers"], plans)):
-                h = linear_apply(p, lp.gplan.apply(h))
-                if i + 1 < n_layers:
-                    h = act(h)
+                with jax.named_scope(f"layer{i}"):
+                    agg = lp.gplan.apply(h)
+                    with jax.named_scope("update"):
+                        h = linear_apply(p, agg)
+                        if i + 1 < n_layers:
+                            h = act(h)
             return h
         for i, (p, lp) in enumerate(zip(params["layers"], plans)):
-            h = lp.apply(h, p["w"], p.get("b"), relu=i + 1 < n_layers)
+            with jax.named_scope(f"layer{i}"):
+                h = lp.apply(h, p["w"], p.get("b"), relu=i + 1 < n_layers)
         return h
     for i, p in enumerate(params["layers"]):
-        h = _aggregate(h, graph, executor, plan, ell)
-        h = linear_apply(p, h)
-        if i + 1 < n_layers:
-            h = act(h)
+        with jax.named_scope(f"layer{i}"):
+            h = _aggregate(h, graph, executor, plan, ell)
+            with jax.named_scope("update"):
+                h = linear_apply(p, h)
+                if i + 1 < n_layers:
+                    h = act(h)
     return h
 
 

@@ -9,9 +9,11 @@ reports through, with the same clock and the same schema:
   histograms (fixed log-spaced buckets, bounded memory, percentile error
   bounded by one bucket ratio).  Gated on a module-level enabled flag; the
   disabled fast path is one attribute load and a branch.
-* :mod:`repro.obs.trace`    — span tracer emitting Perfetto /
-  chrome://tracing JSON.  ``span()`` is a shared no-op singleton while no
-  tracer is installed.
+* :mod:`repro.obs.trace`    — ``span()``: while enabled, a
+  ``jax.profiler`` annotation (the program's spans and the device ops on
+  one clock in a profiler trace, plus a ``host.gc`` span per collection);
+  while a tracer is installed, Perfetto / chrome://tracing JSON.  A shared
+  no-op singleton while both are off.
 * :mod:`repro.obs.export`   — run provenance (git SHA, device kind, jax
   version), the shared event schema benchmarks emit through, and the
   ``--metrics-out FILE.jsonl`` dump.
@@ -28,8 +30,9 @@ reports through, with the same clock and the same schema:
   (``python -m repro.obs.regress compare BASE.json CURRENT.json``).
 
 Instrumented surfaces: ``exec`` (plan compiles, autotune trials, DP schedule
-verdicts, modeled HBM bytes), ``serve`` (request spans, batcher queue depth
-and flush reasons, per-layer cache hit rates), ``dist`` (halo bytes/chip,
+verdicts, modeled HBM bytes), ``serve`` (batch, session, cache and layer
+spans, queue wait, batcher queue depth and flush reasons, per-layer cache
+hit rates), ``dist`` (halo bytes/chip,
 send/recv plan sizes), ``train`` (step time, rows/sec, executor verdict).
 Turn it on with ``obs.enable()`` + ``obs.start_trace()``, or the
 ``--metrics-out`` / ``--trace`` flags on ``launch/train.py`` and

@@ -29,6 +29,8 @@ import math
 import threading
 from typing import Dict, Optional, Tuple
 
+from . import trace as _trace
+
 
 class _State:
     __slots__ = ("enabled",)
@@ -40,13 +42,19 @@ class _State:
 _STATE = _State()
 
 
+def _set(on: bool) -> None:
+    _STATE.enabled = on
+    _trace.annotate(on)
+
+
 def enable() -> None:
-    """Turn gated metric recording on (module-level flag)."""
-    _STATE.enabled = True
+    """Turn gated metric recording on (module-level flag), and with it the
+    spans' profiler annotations and ``host.gc`` spans (:mod:`.trace`)."""
+    _set(True)
 
 
 def disable() -> None:
-    _STATE.enabled = False
+    _set(False)
 
 
 def enabled() -> bool:
@@ -62,11 +70,11 @@ class enabled_scope:
 
     def __enter__(self):
         self._prev = _STATE.enabled
-        _STATE.enabled = self._on
+        _set(self._on)
         return self
 
     def __exit__(self, *exc):
-        _STATE.enabled = self._prev
+        _set(self._prev)
         return False
 
 
@@ -219,7 +227,7 @@ class Histogram:
                 "max": 0.0 if empty else self.max,
                 "mean": 0.0 if empty else self.sum / self.count,
                 "p50": self.percentile(50), "p90": self.percentile(90),
-                "p99": self.percentile(99)}
+                "p95": self.percentile(95), "p99": self.percentile(99)}
 
 
 # ---------------------------------------------------------------------------

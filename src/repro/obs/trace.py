@@ -1,18 +1,31 @@
-"""Span tracer emitting Perfetto / chrome://tracing-compatible JSON.
+"""Span tracer: one ``span()`` feeding the JAX profiler and a Chrome JSON.
 
 The trace is the "same clock" half of the observability story: autotune
 trials, DP scheduling, serve request batches, and train steps all become
-*complete* events (``ph: "X"``) on one ``time.perf_counter`` timeline, so a
-single Perfetto load shows where a run's wall-clock went across every level
-of the hierarchy.
+spans.  One ``span()`` call feeds two sinks:
 
-Zero overhead when idle: ``span()``/``instant()`` return a shared no-op
-singleton while no tracer is installed — no allocation, no clock read, no
-formatting.  Install one with :func:`start_trace`, write it out with
-:func:`stop_trace` (or use the :func:`tracing_to` context manager).
+* while :func:`repro.obs.enabled` (``obs.enable()``, or the launchers'
+  ``--metrics-out``), each span also opens a
+  ``jax.profiler.TraceAnnotation``, so inside a ``jax.profiler`` trace the
+  program's spans land on the host plane of the same ``.xplane.pb`` as the
+  device ops, on one clock.  The annotation carries the span's category
+  as ``cat``, which tells the program's spans from the runtime's own host
+  events; the other args are formatted into the event only for spans
+  opened with ``profile_args=True``.  While enabled, every garbage
+  collection is recorded as a ``host.gc`` span (``generation``,
+  ``collected``), so that a pause can be put down to one;
+* while a tracer is installed (:func:`start_trace`, or ``--trace
+  FILE.json``), spans become *complete* events (``ph: "X"``) on one
+  ``time.perf_counter`` timeline in Perfetto / chrome://tracing JSON.
 
-Output format (the JSON Object Format of the Trace Event spec, which
-Perfetto and chrome://tracing both accept):
+Zero overhead when idle: with neither sink on, ``span()`` is one attribute
+load, one branch and the shared no-op singleton — no allocation, no clock
+read, no formatting; ``instant()`` likewise.  Install a tracer with
+:func:`start_trace`, write it out with :func:`stop_trace` (or use the
+:func:`tracing_to` context manager).
+
+Output format of the JSON sink (the JSON Object Format of the Trace Event
+spec, which Perfetto and chrome://tracing both accept):
 
     {"traceEvents": [{"name", "cat", "ph", "ts", "dur", "pid", "tid",
                       "args"}, ...],
@@ -23,6 +36,7 @@ Perfetto and chrome://tracing both accept):
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -49,18 +63,32 @@ NOOP_SPAN = _NoopSpan()
 
 
 class Span:
-    """One live span; records a complete ("X") event when exited."""
+    """One live span: a profiler annotation while :mod:`repro.obs` is
+    enabled, and a complete ("X") event on the installed tracer, if any,
+    when exited.  ``cpu=True`` adds the thread CPU time spent inside the
+    span as the arg ``cpu_ms``."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "_ann", "_profile_args", "_cpu", "name", "cat",
+                 "args", "_t0", "_c0")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: dict, profile_args: bool = False, cpu: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self._t0 = 0.0
+        self._profile_args = profile_args
+        self._cpu = cpu
+        self._t0 = self._c0 = 0.0
+        ann = _TRACE.annotation
+        self._ann = (None if ann is None else
+                     ann(name, cat=cat, **(args if profile_args else {})))
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._cpu:
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
@@ -68,11 +96,19 @@ class Span:
         """Attach/overwrite args after the span opened (e.g. a measured
         verdict only known at exit)."""
         self.args.update(kw)
+        if self._profile_args and self._ann is not None:
+            self._ann.set_metadata(**kw)
         return self
 
     def __exit__(self, *exc):
-        self._tracer._complete(self.name, self.cat, self._t0,
-                               time.perf_counter(), self.args)
+        t1 = time.perf_counter()
+        if self._cpu:
+            self.set(cpu_ms=(time.thread_time() - self._c0) * 1e3)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._tracer is not None:
+            self._tracer._complete(self.name, self.cat, self._t0, t1,
+                                   self.args)
         return False
 
 
@@ -136,21 +172,57 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# the installed tracer (module-level, like the registry's enabled flag)
+# the sinks (module-level, like the registry's enabled flag)
 # ---------------------------------------------------------------------------
 class _TraceState:
-    __slots__ = ("tracer",)
+    __slots__ = ("tracer", "annotation", "live", "gc_span")
 
     def __init__(self) -> None:
         self.tracer: Optional[Tracer] = None
+        # jax.profiler.TraceAnnotation while repro.obs is enabled
+        self.annotation = None
+        # either sink on: span()'s one check
+        self.live = False
+        self.gc_span: Optional[Span] = None
 
 
 _TRACE = _TraceState()
 
 
+def _refresh() -> None:
+    _TRACE.live = (_TRACE.tracer is not None
+                   or _TRACE.annotation is not None)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``host.gc`` span per collection."""
+    if phase == "start":
+        sp = span("host.gc", cat="host", profile_args=True,
+                  generation=info["generation"])
+        _TRACE.gc_span = sp.__enter__()
+    elif _TRACE.gc_span is not None:
+        sp, _TRACE.gc_span = _TRACE.gc_span, None
+        sp.set(collected=info["collected"])
+        sp.__exit__(None, None, None)
+
+
+def annotate(on: bool) -> None:
+    """Feed spans to the JAX profiler and record collections, or stop.
+    :func:`repro.obs.enable` and :func:`repro.obs.disable` call this."""
+    if on and _TRACE.annotation is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE.annotation = TraceAnnotation
+        gc.callbacks.append(_on_gc)
+    elif not on and _TRACE.annotation is not None:
+        _TRACE.annotation = None
+        gc.callbacks.remove(_on_gc)
+    _refresh()
+
+
 def start_trace() -> Tracer:
     """Install (and return) a fresh global tracer."""
     _TRACE.tracer = Tracer()
+    _refresh()
     return _TRACE.tracer
 
 
@@ -158,6 +230,7 @@ def stop_trace(path: Optional[str] = None,
                other_data: Optional[dict] = None) -> Optional[dict]:
     """Uninstall the tracer; write/return its JSON doc (None if not tracing)."""
     t, _TRACE.tracer = _TRACE.tracer, None
+    _refresh()
     if t is None:
         return None
     if path is not None:
@@ -173,17 +246,20 @@ def current_tracer() -> Optional[Tracer]:
     return _TRACE.tracer
 
 
-def span(name: str, cat: str = "repro", **args):
-    """A span on the installed tracer, or the shared no-op when idle.
+def span(name: str, cat: str = "repro", *, profile_args: bool = False,
+         cpu: bool = False, **args):
+    """A span on the live sinks, or the shared no-op when both are off.
 
-    The no-op path is one attribute load and a ``None`` check — safe to
-    leave in warm code.  Truly per-element hot loops (kernel grid steps,
-    per-edge work) should not call even this.
+    The no-op path is one attribute load and a branch — safe to leave in
+    warm code.  Truly per-element hot loops (kernel grid steps, per-edge
+    work) should not call even this.  ``profile_args=True`` formats
+    ``args`` (and later ``set`` args) into the profiler event too, for the
+    spans a trace reader needs them on; ``cpu=True`` records the thread CPU
+    time spent inside as ``cpu_ms``.
     """
-    t = _TRACE.tracer
-    if t is None:
+    if not _TRACE.live:
         return NOOP_SPAN
-    return t.span(name, cat, **args)
+    return Span(_TRACE.tracer, name, cat, args, profile_args, cpu)
 
 
 def instant(name: str, cat: str = "repro", **args) -> None:

@@ -7,13 +7,17 @@ buckets are padded to the next power of two (duplicating the last live id, a
 mask marks live rows), so the engine jit-compiles each bucket size exactly
 once — ``log2(max_batch)+1`` compilations total, no matter the traffic.
 
-Time is explicit everywhere (``t`` arguments, no wall-clock reads), so the
-batcher is deterministic under simulated traces and trivially testable.
+Batching time is explicit everywhere (``t`` arguments, no wall-clock
+reads), so the batcher is deterministic under simulated traces and trivially
+testable.  The one clock read is the host-clock stamp of each submit
+(``clock``, ``time.perf_counter`` by default), which a flushed batch carries
+as ``t_submit`` so the engine can measure how long each request queued.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+import time
+from typing import Callable, List, Optional
 
 import numpy as np
 
@@ -38,6 +42,7 @@ class MicroBatch:
     valid: np.ndarray             # (pow2,) bool
     t_flush: float
     reason: str                   # "full" | "deadline" | "drain"
+    t_submit: np.ndarray          # (live,) batcher-clock submit stamps
 
     @property
     def num_live(self) -> int:
@@ -65,22 +70,33 @@ class MicroBatcher:
     """
 
     def __init__(self, max_batch: int = 64, max_wait: float = 2e-3,
-                 max_queue: Optional[int] = None):
+                 max_queue: Optional[int] = None,
+                 clock: Callable[[], float] = time.perf_counter):
         assert max_batch >= 1 and (max_batch & (max_batch - 1)) == 0, \
             "max_batch must be a power of two (bucket discipline)"
         self.max_batch = max_batch
         self.max_wait = float(max_wait)
         self.max_queue = max_queue
+        self.clock = clock
         self.pending: List[Request] = []
+        self._stamps: List[float] = []   # clock() at each pending submit
         self.depth_hwm = 0            # deepest the queue ever got
         self.shed = 0                 # arrivals refused by try_submit
+        # metric handles taken once: a disabled set/inc per request is one
+        # attribute load and a branch
+        self._depth = obs.gauge("serve.queue_depth")
+        self._depth_hwm = obs.gauge("serve.queue_depth_hwm")
+        self._flushes = {r: obs.counter("serve.flush", reason=r)
+                         for r in ("full", "deadline", "drain")}
+        self._flush_size = obs.histogram("serve.flush_size", lo=1.0,
+                                         hi=1e5, per_decade=20)
 
     def _flush(self, t: float, reason: str) -> MicroBatch:
-        obs.counter("serve.flush", reason=reason).inc()
-        obs.histogram("serve.flush_size", lo=1.0, hi=1e5,
-                      per_decade=20).observe(float(len(self.pending)))
+        self._flushes[reason].inc()
+        self._flush_size.observe(float(len(self.pending)))
         reqs, self.pending = self.pending, []
-        obs.gauge("serve.queue_depth").set(0)
+        stamps, self._stamps = self._stamps, []
+        self._depth.set(0)
         ids = np.array([r.node_id for r in reqs], dtype=np.int32)
         b = pow2_bucket(ids.shape[0], self.max_batch)
         pad = b - ids.shape[0]
@@ -88,15 +104,17 @@ class MicroBatcher:
         valid = np.zeros(b, dtype=bool)
         valid[:ids.shape[0]] = True
         return MicroBatch(requests=reqs, node_ids=node_ids, valid=valid,
-                          t_flush=t, reason=reason)
+                          t_flush=t, reason=reason,
+                          t_submit=np.asarray(stamps))
 
     def submit(self, req: Request) -> Optional[MicroBatch]:
         """Add a request at its arrival time; returns a batch if now full."""
         self.pending.append(req)
+        self._stamps.append(self.clock())
         if len(self.pending) > self.depth_hwm:
             self.depth_hwm = len(self.pending)
-            obs.gauge("serve.queue_depth_hwm").set(self.depth_hwm)
-        obs.gauge("serve.queue_depth").set(len(self.pending))
+            self._depth_hwm.set(self.depth_hwm)
+        self._depth.set(len(self.pending))
         if len(self.pending) >= self.max_batch:
             return self._flush(req.t_arrival, "full")
         return None

@@ -28,6 +28,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from ..core.cache_model import LRUCache
 
 
@@ -122,47 +123,51 @@ class EmbeddingCache:
         only called for whole missed lines.  Returns the requested rows.
         """
         nodes = np.asarray(nodes, dtype=np.int64)
-        lru = self.layers[0]
-        out = np.empty((nodes.shape[0], self.layer_dims[0]), self.dtype)
-        lines = self._line_of(nodes)
-        # Sweep in execution order (line-sorted): the aggregation walks the
-        # reorder, so each line is touched exactly once per call even when
-        # the working set exceeds capacity — the paper's reuse-distance
-        # argument applied to the probe stream itself.  Stats are counted
-        # once per distinct line per call (hit == a whole store fetch
-        # avoided); the probes a fresh line serves within the same call are
-        # not "reuse", they're the burst itself.
-        order = np.argsort(lines, kind="stable")
-        cur_line = None
-        entry = None
-        for i in order:
-            u, ln = int(nodes[i]), int(lines[i])
-            if ln != cur_line:
-                cur_line = ln
-                entry = lru.get(ln)
-                if entry is LRUCache.MISS:
-                    ids = self._line_nodes(ln)
-                    vals = np.asarray(loader(ids), dtype=self.dtype)
-                    entry = {int(v): vals[j] for j, v in enumerate(ids)}
-                    lru.put(ln, entry)
-            out[i] = entry[u]
-        return out
+        with obs.span("serve.cache.fetch_base", cat="serve"):
+            lru = self.layers[0]
+            out = np.empty((nodes.shape[0], self.layer_dims[0]), self.dtype)
+            lines = self._line_of(nodes)
+            # Sweep in execution order (line-sorted): the aggregation walks the
+            # reorder, so each line is touched exactly once per call even when
+            # the working set exceeds capacity — the paper's reuse-distance
+            # argument applied to the probe stream itself.  Stats are counted
+            # once per distinct line per call (hit == a whole store fetch
+            # avoided); the probes a fresh line serves within the same call are
+            # not "reuse", they're the burst itself.
+            order = np.argsort(lines, kind="stable")
+            cur_line = None
+            entry = None
+            for i in order:
+                u, ln = int(nodes[i]), int(lines[i])
+                if ln != cur_line:
+                    cur_line = ln
+                    entry = lru.get(ln)
+                    if entry is LRUCache.MISS:
+                        ids = self._line_nodes(ln)
+                        vals = np.asarray(loader(ids), dtype=self.dtype)
+                        entry = {int(v): vals[j] for j, v in enumerate(ids)}
+                        lru.put(ln, entry)
+                out[i] = entry[u]
+            return out
 
     # ---------------------------------------------- deeper layers (per node)
     def lookup(self, layer: int, nodes: np.ndarray):
         """Batch lookup: (hit_mask, values) with values[i]=None on miss."""
         assert layer >= 1, "layer 0 is served via fetch_base"
-        lru = self.layers[layer]
-        vals = [lru.get(int(u)) for u in nodes]
-        mask = np.array([v is not LRUCache.MISS for v in vals], dtype=bool)
-        return mask, [None if v is LRUCache.MISS else v for v in vals]
+        with obs.span("serve.cache.lookup", cat="serve", layer=layer):
+            lru = self.layers[layer]
+            vals = [lru.get(int(u)) for u in nodes]
+            mask = np.array([v is not LRUCache.MISS for v in vals],
+                            dtype=bool)
+            return mask, [None if v is LRUCache.MISS else v for v in vals]
 
     def put_many(self, layer: int, nodes: np.ndarray, mat: np.ndarray) -> None:
         assert layer >= 1
-        lru = self.layers[layer]
-        mat = np.asarray(mat, dtype=self.dtype)
-        for i, u in enumerate(nodes):
-            lru.put(int(u), mat[i])
+        with obs.span("serve.cache.put", cat="serve", layer=layer):
+            lru = self.layers[layer]
+            mat = np.asarray(mat, dtype=self.dtype)
+            for i, u in enumerate(nodes):
+                lru.put(int(u), mat[i])
 
     # -------------------------------------------------------------- warming
     def warm(self, layer: int, order: np.ndarray, values: np.ndarray,

@@ -30,9 +30,8 @@ admission-controlled: when the bounded queue is full or the modeled backlog
 would blow the request's deadline budget, the engine answers **degraded**
 from the final-layer cache with an explicit ``stale`` flag — or *sheds*
 explicitly when the cache cannot help.  Every response is exact or flagged;
-nothing times out silently.  Real wall-time per batch is still measured,
-but only into a gauge (``serve.batch_wall_ms``) so timing noise never
-touches the deterministic accounting.
+nothing times out silently, and wall-clock timing never touches the
+deterministic accounting.
 
 Latency state is a **streaming log-bucket histogram**
 (:class:`repro.obs.Histogram` — fixed bucket count, so memory stays bounded
@@ -41,8 +40,12 @@ p50/p99 come from log-interpolated bucket quantiles with relative error
 bounded by one bucket ratio (~2.3%).  Pass ``keep_records=True`` to also
 retain the per-request :class:`RequestRecord` list for debugging.  When
 :mod:`repro.obs` is enabled the engine additionally mirrors its counters
-into the global registry and opens a span per batch stage (dedupe → embed →
-oracle) plus one per request.
+into the global registry, observes each request's queue wait (batch start
+minus its submit stamp on the batcher's clock) into ``serve.queue_seconds``,
+and opens spans per batch: ``serve.batch`` (sequence number, first and last
+``req_id``, thread CPU time) around ``serve.embed`` (with the session's, the
+cache's and ``serve.engine.assemble`` inside), ``serve.oracle`` and
+``serve.engine.account`` (the per-request latency bookkeeping).
 """
 from __future__ import annotations
 
@@ -194,14 +197,15 @@ class ServeEngine:
             if B.size == 0:
                 continue
             src, dst = edges[l]
-            lut = {int(u): i for i, u in enumerate(B)}
-            dst_index = np.fromiter((lut[int(x)] for x in dst),
-                                    dtype=np.int32, count=dst.shape[0])
-            prev = known[l - 1]
-            d_prev = sess.layer_dims[l - 1]
-            src_h = (np.stack([prev[int(u)] for u in src])
-                     if src.size else np.empty((0, d_prev), np.float32))
-            self_h = np.stack([prev[int(u)] for u in B])
+            with obs.span("serve.engine.assemble", cat="serve", layer=l):
+                lut = {int(u): i for i, u in enumerate(B)}
+                dst_index = np.fromiter((lut[int(x)] for x in dst),
+                                        dtype=np.int32, count=dst.shape[0])
+                prev = known[l - 1]
+                d_prev = sess.layer_dims[l - 1]
+                src_h = (np.stack([prev[int(u)] for u in src])
+                         if src.size else np.empty((0, d_prev), np.float32))
+                self_h = np.stack([prev[int(u)] for u in B])
             h = sess.layer_forward(l, B, src, dst_index, src_h, self_h)
             if cache is not None:
                 cache.put_many(l, B, h)
@@ -236,13 +240,18 @@ class ServeEngine:
     # -------------------------------------------------------------- serving
     def process_batch(self, mb: MicroBatch) -> np.ndarray:
         """Serve one flushed micro-batch; returns (live, d) embeddings."""
-        with obs.span("serve.batch", cat="serve",
-                      size=int(mb.valid.sum())) as bsp:
+        reqs = mb.requests
+        with obs.span("serve.batch", cat="serve", profile_args=True,
+                      cpu=True, seq=self.num_batches,
+                      first=reqs[0].req_id, last=reqs[-1].req_id):
             t0 = time.perf_counter()
-            with obs.span("serve.dedupe", cat="serve"):
-                live_ids = mb.node_ids[mb.valid]
-                unique_ids, inverse = np.unique(live_ids,
-                                                return_inverse=True)
+            if obs.enabled():
+                # looked up per batch, not held: survives an obs.reset()
+                queue = obs.histogram("serve.queue_seconds")
+                for ts in self.batcher.clock() - mb.t_submit:
+                    queue.observe(float(ts))
+            live_ids = mb.node_ids[mb.valid]
+            unique_ids, inverse = np.unique(live_ids, return_inverse=True)
             with obs.span("serve.embed", cat="serve",
                           unique=int(unique_ids.shape[0])):
                 emb = self._embed(unique_ids)[inverse]
@@ -256,41 +265,35 @@ class ServeEngine:
                     errs = np.max(np.abs(emb - ref), axis=-1)
                     self.max_oracle_err = max(self.max_oracle_err,
                                               float(errs.max(initial=0.0)))
-            if self.slo is None:
-                t_done = mb.t_flush + compute_dt
-            else:
-                # modeled completion on the trace clock: deterministic cost
-                # chained through busy_until (real wall time goes to a gauge
-                # only, so timing noise never reaches the accounting)
-                cost = (self.slo.cost_per_batch_s
-                        + self.slo.cost_per_miss_s * self._last_computed)
-                t_done = max(mb.t_flush, self.busy_until) + cost
-                self.busy_until = t_done
-                obs.gauge("serve.batch_wall_ms").set(compute_dt * 1e3)
-            for i, r in enumerate(mb.requests):
-                lat = t_done - r.t_arrival
-                self.lat_hist.observe(lat)
-                self.num_requests += 1
-                self._t_first = min(self._t_first, r.t_arrival)
-                self._t_last = max(self._t_last, t_done)
-                obs.instant("serve.request", cat="serve", req_id=r.req_id,
-                            node_id=r.node_id, latency_ms=lat * 1e3)
-                if self.keep_records:
-                    self.records.append(RequestRecord(
-                        req_id=r.req_id, node_id=r.node_id,
-                        latency=lat, t_done=t_done,
-                        oracle_err=float(errs[i])))
-            obs.counter("serve.requests").inc(len(mb.requests))
-            obs.counter("serve.batches").inc()
-            bsp.set(compute_ms=compute_dt * 1e3)
+            with obs.span("serve.engine.account", cat="serve"):
+                if self.slo is None:
+                    t_done = mb.t_flush + compute_dt
+                else:
+                    # modeled completion on the trace clock: deterministic cost
+                    # chained through busy_until, so timing noise never reaches
+                    # the accounting
+                    cost = (self.slo.cost_per_batch_s
+                            + self.slo.cost_per_miss_s * self._last_computed)
+                    t_done = max(mb.t_flush, self.busy_until) + cost
+                    self.busy_until = t_done
+                for i, r in enumerate(reqs):
+                    lat = t_done - r.t_arrival
+                    self.lat_hist.observe(lat)
+                    self.num_requests += 1
+                    self._t_first = min(self._t_first, r.t_arrival)
+                    self._t_last = max(self._t_last, t_done)
+                    if self.keep_records:
+                        self.records.append(RequestRecord(
+                            req_id=r.req_id, node_id=r.node_id,
+                            latency=lat, t_done=t_done,
+                            oracle_err=float(errs[i])))
+                obs.counter("serve.requests").inc(len(reqs))
+                obs.counter("serve.batches").inc()
         return emb
 
     # ------------------------------------------------- SLO degradation path
     def _record_aside(self, req: Request, outcome: str, stale: bool = False,
                       latency: float = 0.0) -> None:
-        obs.instant("serve.request", cat="serve", req_id=req.req_id,
-                    node_id=req.node_id, latency_ms=latency * 1e3,
-                    outcome=outcome)
         if self.keep_records:
             self.records.append(RequestRecord(
                 req_id=req.req_id, node_id=req.node_id, latency=latency,

@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .batcher import pow2_bucket as _pow2
 from ..graph.structure import Graph
 from ..graph.sampler import FullNeighborhood, NeighborSampler
@@ -138,7 +139,8 @@ class GNNSession:
 
     # ------------------------------------------------------------- serving
     def expand(self, nodes: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        return self._expander.expand(nodes)
+        with obs.span("serve.session.expand", cat="serve"):
+            return self._expander.expand(nodes)
 
     def gather(self, ids: np.ndarray) -> np.ndarray:
         return self.feats[np.asarray(ids, dtype=np.int64)]
@@ -146,25 +148,35 @@ class GNNSession:
     def layer_forward(self, l: int, dst_ids: np.ndarray, edge_src: np.ndarray,
                       dst_index: np.ndarray, src_h: np.ndarray,
                       self_h: np.ndarray) -> np.ndarray:
-        B, E = self_h.shape[0], src_h.shape[0]
-        Bp, Ep = _pow2(B), _pow2(max(E, 1))
-        p = self.params["layers"][l - 1]
-        w = p["w"].astype(jnp.float32)
-        b = p["b"].astype(jnp.float32)
-        src_h_p = _pad_pow2(src_h.astype(np.float32), Ep)
-        self_h_p = _pad_pow2(self_h.astype(np.float32), Bp)
-        dst_p = _pad_pow2(dst_index.astype(np.int32), Ep)
-        is_last = l == self.num_layers
-        if self.kind == "gcn":
-            inv_src = _pad_pow2(self.inv_sqrt[edge_src], Ep)
-            inv_dst = _pad_pow2(self.inv_sqrt[dst_ids], Bp)
-            out = _gcn_layer(w, b, src_h_p, self_h_p, inv_src, inv_dst,
-                             dst_p, is_last=is_last)
-        else:
-            live = _pad_pow2(np.ones(E, np.float32), Ep)
-            out = _sage_layer(w, b, src_h_p, self_h_p, live, dst_p,
-                              is_last=is_last)
-        return np.asarray(out)[:B]
+        """One layer on the device, in three spans inside
+        ``serve.session.layer``: ``pad`` (host-side padding to the pow2
+        bucket), ``launch`` (the jitted call, host-to-device included) and
+        ``readback`` (the wait on the device and the copy back)."""
+        with obs.span("serve.session.layer", cat="serve", layer=l):
+            with obs.span("serve.session.pad", cat="serve"):
+                B, E = self_h.shape[0], src_h.shape[0]
+                Bp, Ep = _pow2(B), _pow2(max(E, 1))
+                p = self.params["layers"][l - 1]
+                w = p["w"].astype(jnp.float32)
+                b = p["b"].astype(jnp.float32)
+                src_h_p = _pad_pow2(src_h.astype(np.float32), Ep)
+                self_h_p = _pad_pow2(self_h.astype(np.float32), Bp)
+                dst_p = _pad_pow2(dst_index.astype(np.int32), Ep)
+                is_last = l == self.num_layers
+                if self.kind == "gcn":
+                    inv_src = _pad_pow2(self.inv_sqrt[edge_src], Ep)
+                    inv_dst = _pad_pow2(self.inv_sqrt[dst_ids], Bp)
+                else:
+                    live = _pad_pow2(np.ones(E, np.float32), Ep)
+            with obs.span("serve.session.launch", cat="serve"):
+                if self.kind == "gcn":
+                    out = _gcn_layer(w, b, src_h_p, self_h_p, inv_src,
+                                     inv_dst, dst_p, is_last=is_last)
+                else:
+                    out = _sage_layer(w, b, src_h_p, self_h_p, live, dst_p,
+                                      is_last=is_last)
+            with obs.span("serve.session.readback", cat="serve"):
+                return np.asarray(out)[:B]
 
     # -------------------------------------------------------------- oracle
     def layer_values(self, l: int) -> np.ndarray:

@@ -33,14 +33,17 @@ class TrainResult:
 def make_train_step(loss_fn: Callable, opt: Optimizer,
                     clip_norm: Optional[float] = 1.0,
                     donate: bool = True):
-    """Returns jit'd (params, opt_state, batch) -> (params, opt_state, loss)."""
+    """Returns jit'd (params, opt_state, batch) -> (params, opt_state, loss).
+    Clipping and the optimizer update trace under the ``optimizer`` name
+    scope."""
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        if clip_norm:
-            grads, _ = clip_by_global_norm(grads, clip_norm)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return apply_updates(params, updates), opt_state, loss
+        with jax.named_scope("optimizer"):
+            if clip_norm:
+                grads, _ = clip_by_global_norm(grads, clip_norm)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return apply_updates(params, updates), opt_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
