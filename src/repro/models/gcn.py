@@ -11,6 +11,12 @@ reductions).  executor in {"segment", "shared", "blockell", "fused"}:
 one fused differentiable launch; "fused" goes one level further — each layer
 is a ``repro.exec.LayerExecutionPlan`` call, so aggregation AND the update
 matmul (+bias+ReLU) are one scheduled op with autotuned computation order.
+
+The other executors run each layer as an aggregation and an update, in the
+order ``repro.exec.plan.choose_order`` picks from the layer's static shapes:
+``A_hat (h W) == (A_hat h) W``, so a shrinking layer multiplies by ``W``
+first and aggregates the narrower rows.  The bias always follows the
+aggregation (``A_hat``'s rows do not sum to 1).
 """
 from __future__ import annotations
 
@@ -19,8 +25,10 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..nn.layers import linear_init, linear_apply, cross_entropy
 from ..core.aggregate import segment_aggregate, shared_aggregate, blockell_matmul
+from ..exec.plan import choose_order
 
 
 def gcn_init(key, dims: Sequence[int], param_dtype=jnp.float32) -> Dict:
@@ -93,7 +101,9 @@ def gcn_apply(params, x: jax.Array, graph: Dict[str, Any],
     """Layer ``i`` traces under the name scope ``layer{i}``: its
     aggregation under ``aggregate``, its weight product, bias and activation
     under ``update`` (a fused layer kernel, which does both in one launch,
-    under ``aggregate``).  Backward ops inherit the scopes."""
+    under ``aggregate``).  Backward ops inherit the scopes.  Outside
+    ``executor="fused"`` each layer's order is ``choose_order``'s verdict on
+    its shapes, counted as ``model.gcn.order`` once per trace."""
     h = x
     n_layers = len(params["layers"])
     if executor == "fused":
@@ -120,10 +130,18 @@ def gcn_apply(params, x: jax.Array, graph: Dict[str, Any],
                 h = lp.apply(h, p["w"], p.get("b"), relu=i + 1 < n_layers)
         return h
     for i, p in enumerate(params["layers"]):
+        order = choose_order(h.shape[0], graph["src"].shape[0], *p["w"].shape)
+        obs.counter("model.gcn.order", order=order).inc()
         with jax.named_scope(f"layer{i}"):
+            if order == "update_first":
+                with jax.named_scope("update"):
+                    h = h @ p["w"].astype(h.dtype)
             h = _aggregate(h, graph, executor, plan, ell)
             with jax.named_scope("update"):
-                h = linear_apply(p, h)
+                if order == "aggregate_first":
+                    h = linear_apply(p, h)
+                elif "b" in p:
+                    h = h + p["b"].astype(h.dtype)
                 if i + 1 < n_layers:
                     h = act(h)
     return h
