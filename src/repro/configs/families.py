@@ -233,9 +233,12 @@ class GNNBundle:
             return gcn_init(key, [d_feat, *self.model_kw["hidden"],
                                   self.n_classes])
         if self.arch == "gat":
-            return gat_init(key, d_feat, self.model_kw["d_hidden"],
-                            self.model_kw["n_heads"], self.n_classes,
-                            self.model_kw["n_layers"])
+            kw = self.model_kw
+            return gat_init(key, d_feat, kw["d_hidden"], kw["n_heads"],
+                            self.n_classes, kw["n_layers"],
+                            final_heads=kw.get("final_heads", 1),
+                            residual=kw.get("residual", False),
+                            final_bias=kw.get("final_bias", False))
         if self.arch == "pna":
             return pna_init(key, d_feat, self.model_kw["d_hidden"],
                             self.model_kw["n_layers"], self.n_classes)
@@ -267,6 +270,8 @@ class GNNBundle:
         else:
             base["x"] = SDS((n, d), jnp.float32)
             base["deg"] = SDS((n,), jnp.float32)
+            if self.arch == "gat":
+                base["out_deg"] = SDS((n,), jnp.float32)
         return base
 
     def loss_fn(self, shape: str, executor: str = "segment",
@@ -297,14 +302,19 @@ class GNNBundle:
                 return jnp.mean((jnp.sum(e) - batch["energy_target"]) ** 2)
             graph = {"src": batch["src"], "dst": batch["dst"],
                      "edge_mask": batch["edge_mask"], "deg": batch["deg"],
-                     "mean_log_deg": 2.0}
+                     "out_deg": batch.get("out_deg"), "mean_log_deg": 2.0}
             mask = batch["train_mask"]
             if self.arch == "gcn":
                 return gcn_loss(params, batch["x"], graph, batch["labels"],
                                 mask, executor=executor, ell=exec_plan)
             if self.arch == "gat":
+                kw = self.model_kw
                 return gat_loss(params, batch["x"], graph, batch["labels"],
-                                mask)
+                                mask,
+                                act=getattr(jax.nn,
+                                            kw.get("activation", "elu")),
+                                self_loops=kw.get("self_loops", False),
+                                norm=kw.get("norm", False))
             if self.arch == "pna":
                 return pna_loss(params, batch["x"], graph, batch["labels"],
                                 mask)
@@ -339,6 +349,8 @@ class GNNBundle:
                              "energy_target": NamedSharding(mesh, P())})
         else:
             batch_sh.update({"x": node2, "deg": node})
+            if self.arch == "gat":
+                batch_sh["out_deg"] = node
         out_sh = (rep, opt_sh, NamedSharding(mesh, P()))
         return (rep, opt_sh, batch_sh), out_sh
 

@@ -32,10 +32,12 @@ def segment_aggregate(x: jax.Array, src: jax.Array, dst: jax.Array,
                       num_nodes: int, op: str = "sum",
                       edge_weight: Optional[jax.Array] = None,
                       edge_mask: Optional[jax.Array] = None) -> jax.Array:
-    """a[v] = op_{(u->v)} (w_uv * x[u]).  op in {sum, mean, max, min}."""
+    """a[v] = op_{(u->v)} (w_uv * x[u]).  op in {sum, mean, max, min};
+    ``edge_weight`` is (E,), or (E, d) for a weight per feature."""
     msgs = x[src]
     if edge_weight is not None:
-        msgs = msgs * edge_weight[:, None]
+        msgs = msgs * (edge_weight if edge_weight.ndim == 2
+                       else edge_weight[:, None])
     if edge_mask is not None:
         if op in ("sum", "mean"):
             msgs = jnp.where(edge_mask[:, None], msgs, 0.0)

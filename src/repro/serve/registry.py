@@ -20,7 +20,7 @@ entry point the launcher and benchmarks use.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -78,11 +78,13 @@ class GNNSession:
     ``expander='full'`` (default) aggregates every in-edge with global
     degrees, so block outputs equal the offline full-graph forward row-for-row
     — the engine's oracle check is exact.  ``expander='fanout'`` swaps in the
-    GraphSAGE sampler for approximate high-throughput serving.
+    GraphSAGE sampler for approximate high-throughput serving.  ``hidden``
+    is one width (two layers) or one width per hidden layer.
     """
 
     def __init__(self, name: str, g: Graph, kind: str,
-                 hidden: int = 64, out_dim: int = 16, seed: int = 0,
+                 hidden: Union[int, Sequence[int]] = 64, out_dim: int = 16,
+                 seed: int = 0,
                  expander: str = "full", fanouts: Tuple[int, ...] = (10, 10),
                  executor: str = "fused"):
         assert g.node_feat is not None
@@ -92,7 +94,8 @@ class GNNSession:
         self.executor = executor
         self.feats = np.asarray(g.node_feat, dtype=np.float32)
         d_in = self.feats.shape[1]
-        self.dims = [d_in, hidden, out_dim]
+        widths = [hidden] if isinstance(hidden, int) else list(hidden)
+        self.dims = [d_in, *widths, out_dim]
         key = jax.random.PRNGKey(seed)
         if kind == "gcn":
             self.params = gcn_init(key, self.dims)
