@@ -26,11 +26,16 @@ them, which at ogbn-arxiv's size is what fits one chip.
 
 Parameters: one dict per layer, ``{"a_dst", "a_src", "res", "w"}`` (``w``
 and ``res`` of shape ``(d_in, H * F)``, ``a_*`` of ``(H, F)``), the last
-also ``"b"``.
+also ``"b"``.  :func:`init` draws them from a run's seed: ``w`` and
+``res`` ``N(0, 1/d_in)``, ``a_*`` ``N(0, 0.01)``, ``b`` zero, the
+distribution of the program's GAT, drawn here and not by the program.
 """
 from __future__ import annotations
 
 from typing import Callable, Dict, List, Sequence
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +43,28 @@ import jax.numpy as jnp
 import reference
 
 NEGATIVE_SLOPE = 0.2
+
+
+@functools.partial(jax.jit, static_argnames=("d_in", "heads", "widths"))
+def init(key, d_in: int, heads: Sequence[int], widths: Sequence[int]):
+    """The weights of a GAT whose layer ``l`` has ``heads[l]`` heads of
+    ``widths[l]`` features; hidden layers concatenate their heads."""
+    layers = []
+    for i, (k, h, f) in enumerate(zip(jax.random.split(key, len(heads)),
+                                      heads, widths)):
+        k_w, k_r, k_s, k_d = jax.random.split(k, 4)
+        scale = 1.0 / math.sqrt(d_in)
+        layer = {"a_dst": 0.1 * jax.random.normal(k_d, (h, f), jnp.float32),
+                 "a_src": 0.1 * jax.random.normal(k_s, (h, f), jnp.float32),
+                 "res": scale * jax.random.normal(k_r, (d_in, h * f),
+                                                  jnp.float32),
+                 "w": scale * jax.random.normal(k_w, (d_in, h * f),
+                                                jnp.float32)}
+        if i + 1 == len(heads):
+            layer["b"] = jnp.zeros((f,), jnp.float32)
+        layers.append(layer)
+        d_in = h * f
+    return layers
 
 
 def with_self_loops(src, dst, n: int):

@@ -114,23 +114,23 @@ def edge_exp(scores: jax.Array, dst: jax.Array, num_nodes: int,
 
 
 def edge_softmax(scores: jax.Array, dst: jax.Array, num_nodes: int,
-                 edge_mask: Optional[jax.Array] = None,
-                 self_scores: Optional[jax.Array] = None):
+                 edge_mask: Optional[jax.Array] = None) -> jax.Array:
     """Numerically-stable softmax over incoming edges per destination
-    (arguments as :func:`edge_exp`).  With ``self_scores`` returns
-    ``(alpha, alpha_self)``: the self loops share each softmax."""
-    ex, ex_self = edge_exp(scores, dst, num_nodes, edge_mask, self_scores)
+    (arguments as :func:`edge_exp`)."""
+    ex, _ = edge_exp(scores, dst, num_nodes, edge_mask)
     den = jax.ops.segment_sum(ex, dst, num_segments=num_nodes)
-    if self_scores is None:
-        return ex / jnp.maximum(den[dst], 1e-9)
-    den = den + ex_self
-    return ex / den[dst], ex_self / den
+    return ex / jnp.maximum(den[dst], 1e-9)
+
+
+def one_head_at_a_time(heads: int, features: int) -> bool:
+    """Whether a layer's edge pass runs head by head: its ``H * F`` is
+    wider than one 128-lane row."""
+    return heads * features > LANES
 
 
 def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
               negative_slope: float = 0.2, self_loops: bool = False,
-              norm: bool = False, per_head: Optional[bool] = None
-              ) -> jax.Array:
+              norm: bool = False) -> jax.Array:
     """One layer's per-head outputs, concatenated: ``(N, H * F)``, the
     residual included.  Scopes: ``update`` (the projections),
     ``attention`` (scores and the softmax's numerators), ``aggregate``
@@ -141,16 +141,14 @@ def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
     each head's ``z_h`` goes into the edge pass with a column of ones, so
     that one scatter gives ``sum_j ex_ij z_j`` and its denominator
     ``sum_j ex_ij`` together (one pass over the edges fewer each way).
-    ``per_head`` (default: ``H * F > LANES``) computes each head's
-    ``z_h = h W_h`` and sum in a loop over the heads, each under
-    ``jax.checkpoint``: the backward pass recomputes ``z_h`` and the
-    gathered ``z_h[src]`` rather than storing them, and keeps only the
-    sum.  Otherwise all heads go in one pass."""
+    Where :func:`one_head_at_a_time`, each head's ``z_h = h W_h`` and sum
+    run in a loop over the heads, each under ``jax.checkpoint``: the
+    backward pass recomputes ``z_h`` and the gathered ``z_h[src]`` rather
+    than storing them, and keeps only the sum.  Otherwise all heads go in
+    one pass, each edge's weight repeated over its head's columns."""
     N = h.shape[0]
     H, F = p["a_src"].shape
     src, dst, mask = graph["src"], graph["dst"], graph.get("edge_mask")
-    if per_head is None:
-        per_head = H * F > LANES
     w = p["w"]["w"].astype(h.dtype)
     w_heads = w.reshape(w.shape[0], H, F)
     with jax.named_scope("attention"):
@@ -182,7 +180,7 @@ def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
             zs = [z[:, i * F:(i + 1) * F] for i in range(k)]
             # per head F features and a 1: the sum and its denominator
             aug = jnp.concatenate([c for zi in zs for c in (zi, ones)], 1)
-            weight = jnp.repeat(ex, F + 1, axis=1)
+            weight = ex[:, 0] if k == 1 else jnp.repeat(ex, F + 1, axis=1)
             agg = checkpoint_name(segment_aggregate(
                 aug, src, dst, N, edge_weight=weight), "gat_sum")
             out = []
@@ -196,7 +194,7 @@ def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
             m = jnp.concatenate(out, axis=1) if k > 1 else out[0]
             return m * sqrt_in if norm else m
 
-    if per_head:                    # a loop, so that one head runs at a time
+    if one_head_at_a_time(H, F):    # a loop, so that one head runs at a time
         one = jax.checkpoint(
             lambda wk, a, b: heads_out(wk, a[:, None], b[:, None]),
             policy=jax.checkpoint_policies.save_only_these_names("gat_sum"))
@@ -224,13 +222,12 @@ def gat_apply(params, x: jax.Array, graph: Dict[str, Any],
     for i, p in enumerate(params["layers"]):
         # geometry recovered from parameter shapes (heads, d_out static)
         n_heads, d_out = p["a_src"].shape
-        per_head = n_heads * d_out > LANES
+        per_head = one_head_at_a_time(n_heads, d_out)
         obs.counter("model.gat.edge_passes", layer=i,
                     heads=1 if per_head else n_heads).inc(
                         n_heads if per_head else 1)
         with jax.named_scope(f"layer{i}"):
-            out = gat_layer(p, h, graph, self_loops=self_loops, norm=norm,
-                            per_head=per_head)
+            out = gat_layer(p, h, graph, self_loops=self_loops, norm=norm)
             with jax.named_scope("update"):
                 if i + 1 < n_layers:
                     h = act(out)                        # concat heads

@@ -19,6 +19,7 @@ from conftest import REPO, run_cell
 
 import flops_gat
 import harness
+import loadgen
 import readings
 import reference
 from run import Timers
@@ -195,6 +196,35 @@ def test_a_program_without_the_arxiv_options_stops_at_once(
         _driver(gat_checkout, "gat-tiny.train")
 
 
+def test_the_gat_weights_come_from_the_seed_not_the_program(
+        gat_checkout, monkeypatch):
+    """Both sides start from weights the benchmark draws: a program whose
+    own initial values are degenerate (all zero) changes nothing of them."""
+    from repro.configs.families import GNNBundle
+    import reference_gat
+    real = GNNBundle.init_params
+    monkeypatch.setattr(GNNBundle, "init_params", lambda self, key, d: (
+        jax.tree_util.tree_map(jnp.zeros_like, real(self, key, d))))
+    harness.add_program_path()
+    with jax.default_matmul_precision("float32"):
+        drv, d, _ = _driver(gat_checkout, "gat-tiny.train")
+    d.release()
+    cfg = d.cfg
+    heads, widths = drv.geometry(cfg)
+    want = reference_gat.init(jax.random.fold_in(harness.seed_key(11), 1),
+                              cfg["graph"]["feat_dim"], tuple(heads),
+                              tuple(widths))
+    got = jax.tree_util.tree_leaves(d.params0)
+    assert len(got) == len(jax.tree_util.tree_leaves(want))
+    for a, b in zip(got, jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    for layer in d.params0:            # N(0, 1/d_in) and N(0, 0.01)
+        d_in = layer["w"].shape[0]
+        for k, sd in (("w", d_in ** -0.5), ("res", d_in ** -0.5),
+                      ("a_src", 0.1), ("a_dst", 0.1)):
+            assert abs(np.std(layer[k]) / sd - 1) < 0.25, k
+
+
 def test_a_sound_deep_serving_run_is_correct(gat_checkout):
     out = run_cell(gat_checkout, "gcn-tiny3.serve")
     assert out["correct"], out["checks"]
@@ -203,6 +233,37 @@ def test_a_sound_deep_serving_run_is_correct(gat_checkout):
 
 
 # --------------------------------------------------------------- serving
+def test_the_deep_traced_window_asks_for_the_next_nodes(gat_checkout):
+    """The traced window's requests are the picks that follow the measured
+    window's in the traffic's stream, not a replay of its first ones."""
+    import contextlib
+    harness.add_program_path()
+    with jax.default_matmul_precision("float32"):
+        drv, d, _ = _driver(gat_checkout, "gcn-tiny3.serve")
+        d.window(2.0)
+        n = len(d.run["t"])
+        seen = []
+        real = d._open_loop
+
+        def record(sched, annotate=None):
+            seen.append(sched.nodes.copy())
+            return real(sched, annotate)
+        d._open_loop = record
+        d.traced_window(1.0, lambda name: contextlib.nullcontext())
+    t = d.traffic
+    m = len(seen[0])
+    stream = loadgen.zipf_poisson(d.data.num_nodes, t["rate"],
+                                  (n + m) / t["rate"], t["zipf_a"],
+                                  t["popularity_seed"], 0).nodes
+    window = loadgen.zipf_poisson(d.data.num_nodes, t["rate"],
+                                  n / t["rate"], t["zipf_a"],
+                                  t["popularity_seed"], 0).nodes
+    # the window's picks and the traced ones make up the longer stream
+    assert sorted(np.concatenate([window, seen[0]])) == sorted(stream)
+    assert sorted(window) == sorted(d.run["nodes"])
+    assert d._skip == 0
+
+
 def test_a_three_layer_session_serves_the_reference_rows():
     """``GNNSession`` at two hidden widths answers every node with the row
     of ``reference.forward`` (the float32 GCN of the benchmark)."""

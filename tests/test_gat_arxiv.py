@@ -106,43 +106,55 @@ def test_program_matches_the_reference(arxiv):
         assert np.linalg.norm(a - b) <= 1e-4 * np.linalg.norm(b)
 
 
-def test_the_head_by_head_path_equals_one_pass():
+def test_the_head_by_head_path_equals_one_pass(monkeypatch):
     bundle = GNNBundle("gat", dict(ARXIV, d_hidden=50), n_classes=CLASSES)
     params = bundle.init_params(jax.random.PRNGKey(4), D_IN)
     src, dst, x, labels, mask = _graph(2)
     batch = _batch(src, dst, x, labels, mask)
     p = params["layers"][0]
 
-    def layer_sum(p, per_head):
+    def layer_sum(p, lanes):
+        # 3 x 50 is wider than 128 lanes (head by head) and narrower than
+        # 10**6 (one pass)
+        monkeypatch.setattr(gat, "LANES", lanes)
         return gat.gat_layer(p, batch["x"], batch, self_loops=True,
-                             norm=True, per_head=per_head)
+                             norm=True)
     with jax.default_matmul_precision("float32"):
-        a, b = layer_sum(p, True), layer_sum(p, False)
+        a, b = layer_sum(p, 128), layer_sum(p, 10**6)
         np.testing.assert_allclose(a, b, rtol=0,
                                    atol=1e-6 * float(jnp.abs(b).max()))
-        ga, gb = (jax.grad(lambda q: jnp.sum(layer_sum(q, ph) ** 2))(p)
-                  for ph in (True, False))
+        ga, gb = (jax.grad(lambda q: jnp.sum(layer_sum(q, lanes) ** 2))(p)
+                  for lanes in (128, 10**6))
     for u, v in zip(_leaves(ga), _leaves(gb)):
         assert np.linalg.norm(u - v) <= 1e-5 * np.linalg.norm(v)
 
 
 def test_edge_softmax_with_self_loops_and_masked_edges():
-    # node 0: only its self loop; node 1: two live in-edges and a masked
-    # one; node 2: one in-edge, masked, so only its self loop counts
-    src = jnp.asarray([2, 3, 4, 5], jnp.int32)
-    dst = jnp.asarray([1, 1, 1, 2], jnp.int32)
-    mask = jnp.asarray([True, True, False, False])
-    scores = jnp.asarray([0.5, -1.0, 30.0, 40.0])
-    self_scores = jnp.asarray([2.0, 0.25, -3.0, 1.0, 1.0, 1.0])
-    alpha, alpha_self = gat.edge_softmax(scores, dst, 6, mask, self_scores)
-    np.testing.assert_allclose(alpha_self[0], 1.0)
-    np.testing.assert_allclose(alpha_self[2], 1.0)
-    np.testing.assert_allclose(alpha[2:], 0.0)
-    z = np.exp([0.5, -1.0, 0.25])
-    np.testing.assert_allclose(alpha[:2], z[:2] / z.sum(), rtol=1e-6)
-    np.testing.assert_allclose(alpha_self[1], z[2] / z.sum(), rtol=1e-6)
-    total = jax.ops.segment_sum(alpha, dst, num_segments=6) + alpha_self
-    np.testing.assert_allclose(total, 1.0, rtol=1e-6)
+    """Through the layer: node 0 has only its self loop, node 1 two live
+    in-edges and a masked one, node 2 one in-edge, masked, so only its self
+    loop counts.  The masked edges come from a node whose scores are far
+    above the others', so that any weight they got would show."""
+    rng = np.random.default_rng(0)
+    h = rng.standard_normal((6, 4)).astype(np.float32)
+    h[4] *= 30.0
+    h[5] *= 30.0
+    p = {"w": {"w": jnp.asarray(rng.standard_normal((4, 2)), jnp.float32)},
+         "a_src": jnp.asarray([[1.0, 0.5]]), "a_dst": jnp.asarray([[0.3, -0.2]])}
+    graph = {"src": jnp.asarray([2, 3, 4, 5], jnp.int32),
+             "dst": jnp.asarray([1, 1, 1, 2], jnp.int32),
+             "edge_mask": jnp.asarray([True, True, False, False])}
+    with jax.default_matmul_precision("float32"):
+        got = np.asarray(gat.gat_layer(p, jnp.asarray(h), graph,
+                                       self_loops=True))
+    z = h.astype(np.float64) @ np.asarray(p["w"]["w"], np.float64)
+    s, t = z @ [1.0, 0.5], z @ [0.3, -0.2]
+    leaky = lambda v: np.where(v > 0, v, 0.2 * v)
+    np.testing.assert_allclose(got[0], z[0], rtol=1e-5)
+    np.testing.assert_allclose(got[2], z[2], rtol=1e-5)
+    e = leaky(s[[2, 3, 1]] + t[1])
+    alpha = np.exp(e - e.max()) / np.exp(e - e.max()).sum()
+    np.testing.assert_allclose(got[1], alpha @ z[[2, 3, 1]], rtol=1e-5)
+    np.testing.assert_allclose(got[3:], z[3:], rtol=1e-5)
 
 
 # --------------------------------------------------------------- gat-cora
