@@ -21,7 +21,12 @@ Options the arxiv variant (DGL's ogbn-arxiv GAT) adds, each off by default:
 head-averaged logits.
 
 Edge tensors stay two-dimensional: scores heads-major ``(H, E)`` so that the
-edges lie along the lanes, features ``(N, H * F)``.  Where a layer's
+edges lie along the lanes, features ``(N, H * F)``.  Each edge op of the
+attention runs once for all heads of a layer: a pass over the edges costs
+per index, not per lane.  It runs under ``jax.checkpoint`` keeping only
+the segment max: the TPU lays a packed pass out edges-first, one 128-lane
+row per edge, and residuals kept from it stay in that layout (1.2 GB each
+at ogbn-arxiv's 2.3 M edges).  Where a layer's
 ``H * F`` exceeds one 128-lane row, the weighted sum runs one head at a
 time, each under ``jax.checkpoint``: one ``(E, F)`` gathered tensor is live
 at once and the backward pass gathers it again rather than storing it.
@@ -82,42 +87,46 @@ def gat_init(key, d_in: int, d_hidden: int, n_heads: int, n_classes: int,
     return {"layers": layers}
 
 
-def _per_edge(mask: jax.Array, like: jax.Array) -> jax.Array:
-    return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
-
-
 def edge_exp(scores: jax.Array, dst: jax.Array, num_nodes: int,
              edge_mask: Optional[jax.Array] = None,
-             self_scores: Optional[jax.Array] = None):
+             self_scores: Optional[jax.Array] = None,
+             max_name: Optional[str] = None):
     """The softmax's numerators over incoming edges per destination:
     ``exp(score - max)``, the maximum over the destination's live in-edges
     (and its self loop).  Returns ``(ex, ex_self)``; ``ex_self`` is None
     without ``self_scores``.
 
-    scores: (E, ...), edges first (one head's ``(E,)`` row, or ``(E, H)``);
-    self_scores: (N, ...), the scores of one self loop per node.  Masked
+    scores: (..., E), edges along the last axis (one head's ``(E,)`` row,
+    or heads-major ``(H, E)``, each op then one pass for all heads);
+    self_scores: (..., N), the scores of one self loop per node.  Masked
     edges get 0.  The subtracted maximum carries no gradient (the softmax
     does not depend on it), so the backward pass has no segment-max to
-    differentiate.
+    differentiate.  ``max_name`` names that maximum with
+    ``checkpoint_name``, for the caller's ``jax.checkpoint`` policy.
     """
     if edge_mask is not None:
-        scores = jnp.where(_per_edge(edge_mask, scores), scores, -jnp.inf)
-    mx = jax.ops.segment_max(scores, dst, num_segments=num_nodes)
+        scores = jnp.where(edge_mask, scores, -jnp.inf)
+    mx = jnp.full(scores.shape[:-1] + (num_nodes,), -jnp.inf,
+                  scores.dtype).at[..., dst].max(scores)
     if self_scores is not None:
         mx = jnp.maximum(mx, self_scores)
     mx = jax.lax.stop_gradient(jnp.where(jnp.isfinite(mx), mx, 0.0))
-    ex = jnp.exp(scores - mx[dst])
+    if max_name is not None:
+        mx = checkpoint_name(mx, max_name)
+    ex = jnp.exp(scores - mx[..., dst])
     if edge_mask is not None:
-        ex = jnp.where(_per_edge(edge_mask, ex), ex, 0.0)
+        ex = jnp.where(edge_mask, ex, 0.0)
     return ex, (None if self_scores is None
                 else jnp.exp(self_scores - mx))
 
 
 def edge_softmax(scores: jax.Array, dst: jax.Array, num_nodes: int,
                  edge_mask: Optional[jax.Array] = None) -> jax.Array:
-    """Numerically-stable softmax over incoming edges per destination
-    (arguments as :func:`edge_exp`)."""
-    ex, _ = edge_exp(scores, dst, num_nodes, edge_mask)
+    """Numerically-stable softmax over incoming edges per destination.
+    scores: (E, ...), edges first; the other arguments as
+    :func:`edge_exp`."""
+    ex, _ = edge_exp(jnp.moveaxis(scores, 0, -1), dst, num_nodes, edge_mask)
+    ex = jnp.moveaxis(ex, -1, 0)
     den = jax.ops.segment_sum(ex, dst, num_segments=num_nodes)
     return ex / jnp.maximum(den[dst], 1e-9)
 
@@ -137,7 +146,13 @@ def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
     (the weighted sum).
 
     The scores come from ``h (W_h a^h)``, which equals ``(h W_h) a^h``, so
-    no ``z`` is needed for them.  The softmax is normalised after the sum:
+    no ``z`` is needed for them.  They and the softmax's numerators are
+    heads-major ``(H, E)``: each edge op of the attention (the two score
+    gathers, the segment max and the gather of the maximum; in the
+    backward the scatter-adds by source and by destination) is one pass
+    for all heads.  Under ``jax.checkpoint`` only the segment max is kept,
+    so the backward runs the three gathers again: nine passes a layer.
+    The softmax is normalised after the sum:
     each head's ``z_h`` goes into the edge pass with a column of ones, so
     that one scatter gives ``sum_j ex_ij z_j`` and its denominator
     ``sum_j ex_ij`` together (one pass over the edges fewer each way).
@@ -155,12 +170,15 @@ def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
         s = (h @ jnp.einsum("dhf,hf->dh", w_heads, p["a_src"])).T   # (H, N)
         t = (h @ jnp.einsum("dhf,hf->dh", w_heads, p["a_dst"])).T
         leaky = lambda v: jax.nn.leaky_relu(v, negative_slope)
-        ex, ex_self = [], []
-        for k in range(H):          # (E,) rows: edges along the lanes
-            a, b = edge_exp(leaky(s[k][src] + t[k][dst]), dst, N, mask,
-                            leaky(s[k] + t[k]) if self_loops else None)
-            ex.append(a)
-            ex_self.append(b if self_loops else jnp.zeros(N, h.dtype))
+        # (H, E) and (H, N): one pass per edge op for all heads
+        attend = jax.checkpoint(
+            lambda s, t: edge_exp(leaky(s[:, src] + t[:, dst]), dst, N, mask,
+                                  leaky(s + t) if self_loops else None,
+                                  max_name="edge_max"),
+            policy=jax.checkpoint_policies.save_only_these_names("edge_max"))
+        ex, ex_self = attend(s, t)
+        if ex_self is None:
+            ex_self = jnp.zeros((H, N), h.dtype)
     inv_out = (jax.lax.rsqrt(jnp.maximum(graph["out_deg"], 1.0))[:, None]
                if norm else None)
     sqrt_in = (jnp.sqrt(jnp.maximum(graph["deg"], 1.0))[:, None]
@@ -200,11 +218,10 @@ def gat_layer(p, h: jax.Array, graph: Dict[str, Any], *,
             policy=jax.checkpoint_policies.save_only_these_names("gat_sum"))
         _, m = jax.lax.scan(
             lambda _, xs: (None, one(*xs)), None,
-            (w_heads.transpose(1, 0, 2), jnp.stack(ex),
-             jnp.stack(ex_self)))                               # (H, N, F)
+            (w_heads.transpose(1, 0, 2), ex, ex_self))          # (H, N, F)
         out = m.transpose(1, 0, 2).reshape(N, H * F)
     else:
-        out = heads_out(w, jnp.stack(ex, axis=1), jnp.stack(ex_self, axis=1))
+        out = heads_out(w, ex.T, ex_self.T)
     if "res" in p:
         with jax.named_scope("update"):
             out = out + linear_apply(p["res"], h)
@@ -215,8 +232,9 @@ def gat_apply(params, x: jax.Array, graph: Dict[str, Any],
               act=jax.nn.elu, *, self_loops: bool = False,
               norm: bool = False) -> jax.Array:
     """Layer ``i`` traces under the name scope ``layer{i}``; each trace
-    counts its edge passes as ``model.gat.edge_passes`` (``heads``: heads
-    per pass)."""
+    counts its weighted sums' edge passes as ``model.gat.edge_passes`` and
+    its attention's as ``model.gat.attention_passes`` (``heads``: heads per
+    pass; the attention's one pass stands for each of its edge ops)."""
     h = x
     n_layers = len(params["layers"])
     for i, p in enumerate(params["layers"]):
@@ -226,6 +244,8 @@ def gat_apply(params, x: jax.Array, graph: Dict[str, Any],
         obs.counter("model.gat.edge_passes", layer=i,
                     heads=1 if per_head else n_heads).inc(
                         n_heads if per_head else 1)
+        obs.counter("model.gat.attention_passes", layer=i,
+                    heads=n_heads).inc()
         with jax.named_scope(f"layer{i}"):
             out = gat_layer(p, h, graph, self_loops=self_loops, norm=norm)
             with jax.named_scope("update"):

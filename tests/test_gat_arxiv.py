@@ -157,6 +157,67 @@ def test_edge_softmax_with_self_loops_and_masked_edges():
     np.testing.assert_allclose(got[3:], z[3:], rtol=1e-5)
 
 
+# ------------------------------------------------------ packed attention
+def _attention_per_head(s, t, src, dst, mask, n):
+    """The attention as it was before its heads were packed: each head's
+    scores, segment max and numerators one ``(E,)`` row, with self loops."""
+    leaky = lambda v: jax.nn.leaky_relu(v, 0.2)
+    ex, ex_self = [], []
+    for k in range(s.shape[0]):
+        e = jnp.where(mask, leaky(s[k][src] + t[k][dst]), -jnp.inf)
+        e_self = leaky(s[k] + t[k])
+        mx = jax.lax.stop_gradient(jnp.maximum(
+            jax.ops.segment_max(e, dst, num_segments=n), e_self))
+        ex.append(jnp.where(mask, jnp.exp(e - mx[dst]), 0.0))
+        ex_self.append(jnp.exp(e_self - mx))
+    return jnp.stack(ex), jnp.stack(ex_self)
+
+
+def _layer_per_head(p, h, graph):
+    """An arxiv layer (self loops, symmetric norm, residual) with
+    :func:`_attention_per_head` and a plain weighted sum per head."""
+    n = h.shape[0]
+    heads, f = p["a_src"].shape
+    src, dst = graph["src"], graph["dst"]
+    z = (h @ p["w"]["w"]).reshape(n, heads, f)
+    s = jnp.einsum("nhf,hf->hn", z, p["a_src"])
+    t = jnp.einsum("nhf,hf->hn", z, p["a_dst"])
+    ex, ex_self = _attention_per_head(s, t, src, dst, graph["edge_mask"], n)
+    zs = z * jax.lax.rsqrt(graph["out_deg"])[:, None, None]
+    out = []
+    for k in range(heads):
+        num = (jax.ops.segment_sum(ex[k][:, None] * zs[src, k], dst,
+                                   num_segments=n)
+               + ex_self[k][:, None] * zs[:, k])
+        den = jax.ops.segment_sum(ex[k], dst, num_segments=n) + ex_self[k]
+        out.append(num / den[:, None] * jnp.sqrt(graph["deg"])[:, None])
+    return jnp.concatenate(out, axis=1) + h @ p["res"]["w"]
+
+
+def test_packed_attention_matches_the_per_head_formula(arxiv):
+    """Every layer, on a graph with isolated nodes (0, 1, 2) and masked
+    edges, in the one-pass and the head-by-head weighted sums."""
+    bundle, params = arxiv
+    src, dst, x, labels, mask = _graph(5, isolated=(0, 1, 2))
+    batch = _batch(src, dst, x, labels, mask)
+    batch["edge_mask"] = jnp.asarray(
+        np.random.default_rng(5).random(src.shape[0]) < 0.8)
+    h = batch["x"]
+    for p in params["layers"]:
+        with jax.default_matmul_precision("float32"):
+            got = gat.gat_layer(p, h, batch, self_loops=True, norm=True)
+            want = _layer_per_head(p, h, batch)
+            np.testing.assert_allclose(got, want, rtol=0,
+                                       atol=2e-6 * float(jnp.abs(want).max()))
+            g_got, g_want = (jax.grad(lambda q: jnp.sum(f(q) ** 2))(p)
+                             for f in (lambda q: gat.gat_layer(
+                                 q, h, batch, self_loops=True, norm=True),
+                                       lambda q: _layer_per_head(q, h, batch)))
+        for u, v in zip(_leaves(g_got), _leaves(g_want)):
+            assert np.linalg.norm(u - v) <= 1e-5 * np.linalg.norm(v)
+        h = jax.nn.relu(want)
+
+
 # --------------------------------------------------------------- gat-cora
 def _cora_layer_before(p, h, src, dst, mask):
     """The GAT layer as it was before the arxiv options (one pass, scores
@@ -247,3 +308,42 @@ def test_gat_arxiv_is_registered_for_the_dry_run():
     params, _ = bundle.abstract_state("full_graph_sm")
     assert [p["a_src"].shape for p in params["layers"]] == [
         (3, 250), (3, 250), (3, 40)]
+
+
+def test_attention_is_one_pass_per_edge_op_for_all_heads():
+    """The counter reads one pass of 3 heads a layer.  Lowered on the CPU,
+    the gradient of each arxiv layer's attention gathers ``(3, E)`` and
+    scatters into ``(3, N)``: 3 scatters (the segment max and the two
+    scatter-adds of the backward) and 6 gathers (the checkpoint runs the
+    forward's 3 again), and no one-row pass; three one-row heads take 9
+    and 9."""
+    bundle = GNNBundle("gat", dict(ARXIV, d_hidden=50), n_classes=CLASSES)
+    params = bundle.init_params(jax.random.PRNGKey(6), D_IN)
+    batch = _batch(*_graph(4))
+    obs.reset()
+    with obs.enabled_scope(True):
+        jax.eval_shape(bundle.loss_fn("full_graph_sm"), params, batch)
+        counts = {k: v for k, v in obs.snapshot()["counters"].items()
+                  if k.startswith("model.gat.attention_passes")}
+    obs.reset()
+    assert counts == {f"model.gat.attention_passes{{heads=3,layer={i}}}": 1
+                      for i in range(3)}
+
+    h = batch["x"]
+    n, e = h.shape[0], batch["src"].shape[0]
+
+    def counter(f, arg):
+        hlo = jax.jit(jax.grad(f)).lower(arg).as_text(dialect="hlo")
+        return lambda kind, shape: len(
+            re.findall(rf"= f32\[{shape}\]\S* {kind}\(", hlo))
+    for p in params["layers"]:
+        packed = counter(lambda q: jnp.sum(gat.gat_layer(
+            q, h, batch, self_loops=True, norm=True) ** 2), p)
+        assert (packed("gather", f"3,{e}"),
+                packed("scatter", f"3,{n}")) == (6, 3)
+        assert (packed("gather", e), packed("scatter", n)) == (0, 0)
+        h = jnp.zeros((n, p["w"]["w"].shape[1]))
+    per_head = counter(lambda s: sum(jnp.sum(a) for a in _attention_per_head(
+        s, s, batch["src"], batch["dst"], batch["edge_mask"], n)),
+        jnp.ones((3, n)))
+    assert (per_head("gather", e), per_head("scatter", n)) == (9, 9)
